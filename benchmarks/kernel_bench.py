@@ -82,6 +82,7 @@ def _wrapper_smoke():
     kp = jnp.concatenate([kp, jnp.zeros((P - B * 2, ps, KV, hd))], 0)
     vp = jnp.concatenate([v.reshape(B * 2, ps * 2, KV, hd)[:, :ps],
                           jnp.zeros((P - B * 2, ps, KV, hd))], 0)
+    kp, vp = kp.transpose(0, 2, 1, 3), vp.transpose(0, 2, 1, 3)  # head-major
     bt = jnp.array([[0, 1, 8, 8], [2, 3, 8, 8]], jnp.int32)
     pos = jnp.array([50, 60], jnp.int32)
     out.append({"name": "wrapper_paged_decode_attn",
@@ -89,11 +90,11 @@ def _wrapper_smoke():
                     lambda *a: (paged_decode_attn(*a),), q, kp, vp, bt, pos,
                     iters=3)})
 
-    # int8 pages: per-(page, slot, head) absmax scales, dequant in-kernel
+    # int8 pages: per-(page, head, slot) absmax scales, dequant in-kernel
     def q8(x):
         s = jnp.maximum(jnp.max(jnp.abs(x), -1), 1e-8) / 127.0
         qv = jnp.clip(jnp.round(x / s[..., None]), -127, 127).astype(jnp.int8)
-        return qv, s
+        return qv, s[:, :, None, :]
 
     kq, ksc = q8(kp)
     vq, vsc = q8(vp)

@@ -33,6 +33,7 @@ from benchmarks import (
     token_ratio,
     weight_ablation,
 )
+from repro.launch.compile_cache import enable_compile_cache
 
 TABLES = {
     "kappa_table": kappa_table,
@@ -47,6 +48,7 @@ TABLES = {
 
 
 def main() -> None:
+    enable_compile_cache()
     names = sys.argv[1:] or list(TABLES)
     needs_model = any(n != "kernel_bench" for n in names)
     cfg = params = None

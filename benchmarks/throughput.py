@@ -1095,6 +1095,9 @@ def openloop_smoke():
 
 if __name__ == "__main__":
     import sys
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if "--openloop-smoke" in sys.argv:
         openloop_smoke()
         sys.exit(0)

@@ -21,7 +21,7 @@ positions = jnp.arange(S)
 y_ref = attn.attn_forward(p, x, positions, num_heads=H, num_kv_heads=KV,
                           head_dim=hd, window=W, rope_theta=1e4, use_rope=True)
 
-with (jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") else mesh):
+with mesh:
     y_halo, k, v = jax.jit(
         lambda p_, x_: attn.attn_forward_halo(
             p_, x_, num_heads=H, num_kv_heads=KV, head_dim=hd, window=W,

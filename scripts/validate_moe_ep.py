@@ -16,7 +16,7 @@ E, K, d, ff = 8, 2, 16, 32
 p = moe.init_moe(jax.random.PRNGKey(0), d, ff, E, jnp.float32)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, d))
 
-with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") else mesh:
+with mesh:
     y_ref, aux_ref = moe.moe_ffn(p, x, num_experts=E, experts_per_tok=K,
                                  capacity_factor=0.0)
     y_ep, aux_ep = jax.jit(
@@ -28,7 +28,7 @@ np.testing.assert_allclose(np.asarray(y_ep), np.asarray(y_ref),
                            rtol=2e-4, atol=2e-4)
 print("expert-parallel MoE == dropless oracle: OK")
 
-with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") else mesh:
+with mesh:
     y_seq, _ = jax.jit(
         lambda p_, x_: moe.moe_ffn_expert_parallel(
             p_, x_, num_experts=E, experts_per_tok=K, capacity_factor=16.0,

@@ -152,8 +152,8 @@ def _paged_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
     younger sibling's freshly written slot.
 
     ``quantized`` prepends per-token-head fp32 scale refs (same block-table
-    indexed layout as K/V) to ``rest``; dequant happens on the VMEM tile,
-    so int8 KV never materializes as fp32 in HBM."""
+    indexed layout as K/V) to ``rest``; dequant happens in VMEM, so int8
+    KV never materializes as fp32 in HBM."""
     if quantized:
         ks_ref, vs_ref, o_ref, m_s, l_s, acc_s = rest
     else:
@@ -170,13 +170,15 @@ def _paged_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         acc_s[:] = jnp.zeros_like(acc_s)
 
     q = q_ref[0, 0].astype(jnp.float32)           # (R, hd)
-    k = k_ref[0, :, 0].astype(jnp.float32)        # (ps, hd)
-    v = v_ref[0, :, 0].astype(jnp.float32)        # (ps, hd)
-    if quantized:
-        k = k * ks_ref[0]                         # (ps, hd) * (ps, 1)
-        v = v * vs_ref[0]
+    k = k_ref[0, 0].astype(jnp.float32)           # (ps, hd)
+    v = v_ref[0, 0].astype(jnp.float32)           # (ps, hd)
 
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # (R, ps)
+    if quantized:
+        # per-token scales arrive lane-major (1, ps): dequantizing the
+        # scores and probabilities is exact algebra for (k * s_k) and
+        # (v * s_v), and needs no sublane<->lane transpose of the scales
+        s = s * ks_ref[0, 0]
 
     qpos = pos + jax.lax.broadcasted_iota(
         jnp.int32, (R, page_size), 0) // group
@@ -190,7 +192,8 @@ def _paged_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
     r = jnp.exp(m_prev - m_new)
     p = jnp.where(valid, jnp.exp(s - m_new), 0.0)  # guard all-masked tiles
     l_s[:] = l_s[:] * r + jnp.sum(p, axis=-1, keepdims=True)
-    acc_s[:] = acc_s[:] * r + jnp.dot(p, v, preferred_element_type=jnp.float32)
+    pv = p * vs_ref[0, 0] if quantized else p
+    acc_s[:] = acc_s[:] * r + jnp.dot(pv, v, preferred_element_type=jnp.float32)
     m_s[:] = m_new
 
     @pl.when(li == n_pages - 1)
@@ -202,12 +205,12 @@ def paged_decode_attn_pallas(q, k_pages, v_pages, block_tables, pos, *,
                              k_scales=None, v_scales=None,
                              interpret: Optional[bool] = None):
     """Paged GQA flash-decode. q: (B, H, hd); k_pages, v_pages:
-    (P, ps, KV, hd) page pools; block_tables: (B, MP) int32 physical page
-    per logical page; pos: (B,) int32 per-row positions.
+    (P, KV, ps, hd) head-major page pools; block_tables: (B, MP) int32
+    physical page per logical page; pos: (B,) int32 per-row positions.
 
-    ``k_scales``/``v_scales`` (P, ps, KV) fp32 activate the int8 path:
-    pages are dequantized on the VMEM tile (scale pages ride the same
-    block-table scalar prefetch), never as fp32 in HBM.
+    ``k_scales``/``v_scales`` (P, KV, 1, ps) fp32 activate the int8 path:
+    pages are dequantized in VMEM (scale pages ride the same block-table
+    scalar prefetch), never as fp32 in HBM.
 
     Returns (B, H, hd) fp32. See ref.paged_decode_attn_ref for the page
     semantics (entries past pos may alias a trash page — masked)."""
@@ -228,7 +231,7 @@ def paged_prefill_attn_pallas(q, k_pages, v_pages, block_tables, pos0, *,
     bucketed by the caller (scheduler `_chunk_args` style) so jit keys
     stay stable across chunk counts.
 
-    ``k_scales``/``v_scales`` (P, ps, KV) fp32 activate the int8 path.
+    ``k_scales``/``v_scales`` (P, KV, 1, ps) fp32 activate the int8 path.
     Returns (B, C, H, hd) fp32."""
     if interpret is None:
         interpret = interpret_mode()
@@ -240,7 +243,7 @@ def paged_prefill_attn_pallas(q, k_pages, v_pages, block_tables, pos0, *,
 def _paged_attn_jit(q, k_pages, v_pages, block_tables, pos,
                     k_scales, v_scales, *, interpret: bool):
     B, C, H, hd = q.shape
-    P, ps, KV, _ = k_pages.shape
+    P, KV, ps, _ = k_pages.shape
     MP = block_tables.shape[1]
     G = H // KV
     R = C * G
@@ -250,25 +253,22 @@ def _paged_attn_jit(q, k_pages, v_pages, block_tables, pos,
           .reshape(B, KV, R, hd))
     bt_flat = jnp.asarray(block_tables, jnp.int32).reshape(B * MP)
 
-    def kv_map(b, kv, l, bt_ref, pos_ref):
-        # dereference the block table: stream only the row's own pages
-        phys = bt_ref[b * MP + l]
-        return (phys, 0, kv, 0)
-
-    def scale_map(b, kv, l, bt_ref, pos_ref):
-        phys = bt_ref[b * MP + l]
-        return (phys, 0, kv)
+    def page_map(b, kv, l, bt_ref, pos_ref):
+        # dereference the block table: stream only the row's own pages.
+        # Head-major pages make each block's last two dims whole array
+        # dims — (ps, hd) values, (1, ps) scales — as the TPU tiling needs
+        return (bt_ref[b * MP + l], kv, 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, R, hd),
                      lambda b, kv, l, bt_ref, pos_ref: (b, kv, 0, 0)),
-        pl.BlockSpec((1, ps, 1, hd), kv_map),
-        pl.BlockSpec((1, ps, 1, hd), kv_map),
+        pl.BlockSpec((1, 1, ps, hd), page_map),
+        pl.BlockSpec((1, 1, ps, hd), page_map),
     ]
     operands = [bt_flat, jnp.asarray(pos, jnp.int32), qr, k_pages, v_pages]
     if quant:
-        in_specs += [pl.BlockSpec((1, ps, 1), scale_map),
-                     pl.BlockSpec((1, ps, 1), scale_map)]
+        in_specs += [pl.BlockSpec((1, 1, 1, ps), page_map),
+                     pl.BlockSpec((1, 1, 1, ps), page_map)]
         operands += [k_scales, v_scales]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(

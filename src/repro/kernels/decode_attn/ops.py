@@ -20,10 +20,11 @@ def decode_attn(q, k, v, pos, *, window: int = 0, ring: bool = False,
 
 def paged_decode_attn(q, k_pages, v_pages, block_tables, pos, *,
                       k_scales=None, v_scales=None):
-    """Paged flash GQA decode: q (B,H,hd) vs page pool (P,ps,KV,hd)
-    addressed through (B,MP) block tables at per-row positions (B,).
-    Optional (P,ps,KV) fp32 scales switch the pool to int8 with in-kernel
-    dequant. See kernel.py / ref.py for the page semantics."""
+    """Paged flash GQA decode: q (B,H,hd) vs head-major page pool
+    (P,KV,ps,hd) addressed through (B,MP) block tables at per-row
+    positions (B,). Optional (P,KV,1,ps) fp32 scales switch the pool to
+    int8 with in-kernel dequant. See kernel.py / ref.py for the page
+    semantics."""
     return paged_decode_attn_pallas(q, k_pages, v_pages, block_tables, pos,
                                     k_scales=k_scales, v_scales=v_scales,
                                     interpret=interpret_mode())
@@ -32,9 +33,10 @@ def paged_decode_attn(q, k_pages, v_pages, block_tables, pos, *,
 def paged_prefill_attn(q, k_pages, v_pages, block_tables, pos0, *,
                        k_scales=None, v_scales=None):
     """Paged chunk-prefill GQA attention: q (B,C,H,hd) chunk tokens
-    attend causally vs the page pool (P,ps,KV,hd) through (B,MP) block
-    tables starting at per-row positions pos0 (B,). Optional (P,ps,KV)
-    fp32 scales switch the pool to int8 with in-kernel dequant."""
+    attend causally vs the head-major page pool (P,KV,ps,hd) through
+    (B,MP) block tables starting at per-row positions pos0 (B,). Optional
+    (P,KV,1,ps) fp32 scales switch the pool to int8 with in-kernel
+    dequant."""
     return paged_prefill_attn_pallas(q, k_pages, v_pages, block_tables, pos0,
                                      k_scales=k_scales, v_scales=v_scales,
                                      interpret=interpret_mode())

@@ -43,15 +43,24 @@ def decode_attn_ref(q, k, v, pos, *, window: int = 0, ring: bool = False):
 
 
 def _gather_paged_kv(k_pages, v_pages, block_tables, k_scales, v_scales):
-    """Gather each row's pages into a contiguous logical fp32 view
-    (B, MP*ps, KV, hd), dequantizing int8 pages when scales are given."""
+    """Gather each row's head-major pages into a contiguous logical fp32
+    view (B, MP*ps, KV, hd), dequantizing int8 pages when scales are
+    given."""
     B, MP = block_tables.shape
-    P, ps, KV, hd = k_pages.shape
-    k = k_pages[block_tables].reshape(B, MP * ps, KV, hd).astype(jnp.float32)
-    v = v_pages[block_tables].reshape(B, MP * ps, KV, hd).astype(jnp.float32)
+    P, KV, ps, hd = k_pages.shape
+
+    def logical(pages):                   # (B, MP, KV, ps, hd) -> seq view
+        return (pages.transpose(0, 1, 3, 2, 4)
+                .reshape(B, MP * ps, KV, hd).astype(jnp.float32))
+
+    def logical_scales(s):                # (B, MP, KV, 1, ps) -> (B, S, KV, 1)
+        return s[:, :, :, 0].transpose(0, 1, 3, 2).reshape(B, MP * ps, KV, 1)
+
+    k = logical(k_pages[block_tables])
+    v = logical(v_pages[block_tables])
     if k_scales is not None:
-        k = k * k_scales[block_tables].reshape(B, MP * ps, KV)[..., None]
-        v = v * v_scales[block_tables].reshape(B, MP * ps, KV)[..., None]
+        k = k * logical_scales(k_scales[block_tables])
+        v = v * logical_scales(v_scales[block_tables])
     return k, v
 
 
@@ -60,8 +69,9 @@ def paged_decode_attn_ref(q, k_pages, v_pages, block_tables, pos, *,
     """One-token GQA decode attention over a paged KV pool.
 
     q: (B, H, hd) — query for the current token (already rope'd)
-    k_pages, v_pages: (P, ps, KV, hd) — global page pool; physical page p
-        holds ps contiguous token slots of whichever row owns it
+    k_pages, v_pages: (P, KV, ps, hd) — global head-major page pool;
+        physical page p holds ps contiguous token slots of whichever row
+        owns it
     block_tables: (B, MP) int32 — row b's logical page l lives at physical
         page block_tables[b, l]. Entries for pages past a row's current
         position may point anywhere (a shared trash page): validity is
@@ -69,13 +79,13 @@ def paged_decode_attn_ref(q, k_pages, v_pages, block_tables, pos, *,
         only hands out pages covering positions the row will write.
     pos: (B,) int32 — per-row absolute position of the current token
         (its K/V already written into the owning page)
-    k_scales, v_scales: optional (P, ps, KV) fp32 per-token-head scales
-        for int8 page pools (dequantized before attention).
+    k_scales, v_scales: optional (P, KV, 1, ps) fp32 per-token-head
+        scales for int8 page pools (dequantized before attention).
 
     Returns (B, H, hd) fp32.
     """
     B, H, hd = q.shape
-    P, ps, KV, _ = k_pages.shape
+    P, KV, ps, _ = k_pages.shape
     MP = block_tables.shape[1]
     G = H // KV
 
@@ -103,12 +113,12 @@ def paged_prefill_attn_ref(q, k_pages, v_pages, block_tables, pos0, *,
     pos0: (B,) int32 — absolute position of each row's first chunk token;
         chunk token c sits at pos0 + c and attends causally over
         ``kv_pos <= pos0 + c``.
-    k_scales, v_scales: optional (P, ps, KV) fp32 per-token-head scales.
+    k_scales, v_scales: optional (P, KV, 1, ps) fp32 per-token-head scales.
 
     Returns (B, C, H, hd) fp32.
     """
     B, C, H, hd = q.shape
-    P, ps, KV, _ = k_pages.shape
+    P, KV, ps, _ = k_pages.shape
     MP = block_tables.shape[1]
     G = H // KV
 

@@ -14,6 +14,16 @@ front-end (repro.serving.frontend) instead of batch ``run()``: every
 request is submitted and consumed as a concurrent event stream;
 ``--stream`` additionally asserts each stream's tokens reassemble the
 terminal result exactly (the §9 equivalence contract, end to end).
+
+By default the model is the config's 2-layer float32 toy size
+(``ModelConfig.reduced``), which the CPU tests and CI serve.
+``--full-width`` serves the config as published instead: every layer,
+its own widths and dtype, random weights from a seed unless ``--ckpt``
+is given. That size is for an accelerator:
+
+  python -m repro.launch.serve --arch deepseek-r1-distill-qwen-1.5b \
+      --full-width --method kappa --n 5 --problems 4 --max-new 128 \
+      --paged --prefill-chunk 8 --frontend --stream
 """
 from __future__ import annotations
 
@@ -29,6 +39,7 @@ from repro.configs import get_config
 from repro.configs.base import KappaConfig
 from repro.data import tasks
 from repro.data import tokenizer as tok
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.models.frontends import stub_frontend
 from repro.serving import engine
@@ -74,9 +85,9 @@ def _serve_frontend(sched, test, *, deadline_s, stream: bool):
                         toks.append(ev.token)
                     else:
                         res = ev.result
-                if stream:
-                    assert toks == res.tokens, \
-                        f"rid stream diverged from result ({res.status})"
+                if stream and toks != res.tokens:
+                    raise AssertionError(
+                        f"rid stream diverged from result ({res.status})")
                 return res
 
             gens = await asyncio.gather(
@@ -103,10 +114,18 @@ def serve_eval(arch: str, method: str, *, n: int = 5, problems: int = 20,
                deadline_s: float | None = None,
                frontend_serve: bool = False,
                stream: bool = False,
-               kv_dtype: str = "model") -> dict:
+               kv_dtype: str = "model",
+               full_width: bool = False) -> dict:
+    """Serve ``problems`` synthetic prompts with ``method`` and return the
+    metric columns. ``full_width`` serves the config as published (all
+    layers, its widths and dtype); otherwise it is cut to the toy size
+    given by ``num_layers`` and ``d_model``. The toy tokenizer's ids all
+    fall inside any config's vocabulary."""
     if cfg is None:
-        cfg = get_config(arch).reduced(num_layers=num_layers, d_model=d_model,
-                                       vocab_size=tok.VOCAB_SIZE)
+        cfg = get_config(arch)
+        if not full_width:
+            cfg = cfg.reduced(num_layers=num_layers, d_model=d_model,
+                              vocab_size=tok.VOCAB_SIZE)
     if kv_dtype != "model":
         # quantized KV pages: the paged pool stores int8 values + fp32
         # per-token-head scales and halves HBM per page vs bf16/fp32
@@ -176,6 +195,12 @@ def serve_eval(arch: str, method: str, *, n: int = 5, problems: int = 20,
         peak = max(peak, r.peak_cache_bytes)
     out = {
         "arch": arch, "method": method, "n": n,
+        "model": {"layers": cfg.num_layers, "d_model": cfg.d_model,
+                  "heads": cfg.num_heads, "kv_heads": cfg.num_kv_heads,
+                  "head_dim": cfg.resolved_head_dim, "d_ff": cfg.d_ff,
+                  "vocab": cfg.vocab_size, "dtype": cfg.dtype,
+                  "kv_cache_dtype": cfg.kv_cache_dtype},
+        "generated_tokens": lt,
         "accuracy": acc / len(test),
         "final_branch_tokens": fbt / len(test),
         "total_tokens": lt / len(test),
@@ -311,7 +336,12 @@ def main(argv=None):
     ap.add_argument("--stream", action="store_true",
                     help="with --frontend: assert every stream's token "
                          "events reassemble its terminal result exactly")
+    ap.add_argument("--full-width", action="store_true",
+                    help="serve the config as published (all layers, its "
+                         "widths and dtype) instead of the 2-layer float32 "
+                         "toy size; random weights unless --ckpt")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     serve_eval(args.arch, args.method, n=args.n, problems=args.problems,
                ckpt=args.ckpt, max_new=args.max_new,
                scheduler=args.scheduler or args.paged, sched_rows=args.rows,
@@ -321,7 +351,8 @@ def main(argv=None):
                inject_faults=args.inject_faults, max_queue=args.max_queue,
                deadline_s=args.deadline_s,
                frontend_serve=args.frontend or args.stream,
-               stream=args.stream, kv_dtype=args.kv_dtype)
+               stream=args.stream, kv_dtype=args.kv_dtype,
+               full_width=args.full_width)
 
 
 if __name__ == "__main__":

@@ -155,13 +155,46 @@ def init_paged_kv(num_pages: int, page_size: int, num_kv_heads: int,
                   head_dim: int, dtype, quantized: bool = False):
     """Paged pool for a global-attention layer: physical page p holds
     ``page_size`` contiguous token slots of whichever row owns it
-    (DESIGN.md §5). Layout mirrors the full cache with the batch axis
-    replaced by the page axis: (P, ps, KV, hd). The caller reserves one
+    (DESIGN.md §5). Pages are head-major: values (P, KV, ps, hd), int8
+    scales (P, KV, 1, ps). The paged kernel reads one (ps, hd) tile per
+    (page, kv-head); the TPU lowering needs a block's last two dims to be
+    whole array dims or (8, 128)-aligned, which a token-major
+    (P, ps, KV, hd) page read per head cannot be. The caller reserves one
     extra *trash* page (by convention the last physical index) that
     unowned block-table entries alias — writes to it are garbage, reads
     from it are always masked."""
-    return init_full_cache(num_pages, page_size, num_kv_heads, head_dim,
-                           dtype, quantized)
+    shp = (num_pages, num_kv_heads, page_size, head_dim)
+    if quantized:
+        sshp = (num_pages, num_kv_heads, 1, page_size)
+        return {"k": jnp.zeros(shp, jnp.int8), "v": jnp.zeros(shp, jnp.int8),
+                "k_s": jnp.zeros(sshp, jnp.float32),
+                "v_s": jnp.zeros(sshp, jnp.float32)}
+    return {"k": jnp.zeros(shp, dtype), "v": jnp.zeros(shp, dtype)}
+
+
+def pages_from_seq(x, page_size: int, *, scale: bool = False):
+    """Token-major (..., S, KV, hd) values, or (..., S, KV) int8 scales,
+    cut into head-major pages (..., S/ps, KV, ps, hd) or
+    (..., S/ps, KV, 1, ps). S must be a page multiple."""
+    if scale:
+        *lead, S, KV = x.shape
+        x = x.reshape(*lead, S // page_size, page_size, KV)
+        return jnp.swapaxes(x, -1, -2)[..., None, :]
+    *lead, S, KV, hd = x.shape
+    x = x.reshape(*lead, S // page_size, page_size, KV, hd)
+    return jnp.swapaxes(x, -3, -2)
+
+
+def seq_from_pages(pages, *, scale: bool = False):
+    """Inverse of :func:`pages_from_seq`: (..., n, KV, ps, hd) pages →
+    (..., n*ps, KV, hd); (..., n, KV, 1, ps) scales → (..., n*ps, KV)."""
+    if scale:
+        x = jnp.swapaxes(pages[..., 0, :], -1, -2)        # (..., n, ps, KV)
+        *lead, n, ps, KV = x.shape
+        return x.reshape(*lead, n * ps, KV)
+    x = jnp.swapaxes(pages, -3, -2)                        # (..., n, ps, KV, hd)
+    *lead, n, ps, KV, hd = x.shape
+    return x.reshape(*lead, n * ps, KV, hd)
 
 
 def _is_quantized(cache) -> bool:
@@ -480,6 +513,19 @@ def attn_prefill_chunk(p, x, pos0, cache, *, hist_len: int, num_heads: int,
     return y, new_cache
 
 
+def _gather_pages(cache, block_tables, dtype):
+    """Gather-oracle view of each row's pages: (B, MP*ps, KV, hd) K and V
+    in logical token order, int8 pages dequantized to ``dtype``."""
+    ka = seq_from_pages(cache["k"][block_tables])
+    va = seq_from_pages(cache["v"][block_tables])
+    if _is_quantized(cache):
+        ka = _dequantize_kv(
+            ka, seq_from_pages(cache["k_s"][block_tables], scale=True), dtype)
+        va = _dequantize_kv(
+            va, seq_from_pages(cache["v_s"][block_tables], scale=True), dtype)
+    return ka, va
+
+
 def attn_prefill_chunk_paged(p, x, pos0, cache, block_tables, chunk_pages, *,
                              num_heads: int, num_kv_heads: int,
                              head_dim: int, rope_theta: float,
@@ -503,21 +549,21 @@ def attn_prefill_chunk_paged(p, x, pos0, cache, block_tables, chunk_pages, *,
         q = apply_rope(q, qpos, rope_theta)
         k = apply_rope(k, qpos, rope_theta)
 
-    ps = cache["k"].shape[1]
-    MP = block_tables.shape[1]
+    ps = cache["k"].shape[2]
     off = jnp.mod(qpos, ps)
     quant = _is_quantized(cache)
     new_cache = dict(cache)
+    # head-major pages: (page, :, slot) picks a token's (KV, hd) values
     if quant:
         kq, ks = _quantize_kv(k)
         vq, vs = _quantize_kv(v)
-        new_cache["k_s"] = cache["k_s"].at[chunk_pages, off].set(ks)
-        new_cache["v_s"] = cache["v_s"].at[chunk_pages, off].set(vs)
+        new_cache["k_s"] = cache["k_s"].at[chunk_pages, :, 0, off].set(ks)
+        new_cache["v_s"] = cache["v_s"].at[chunk_pages, :, 0, off].set(vs)
     else:
         kq, vq = k, v
-    new_cache["k"] = cache["k"].at[chunk_pages, off].set(
+    new_cache["k"] = cache["k"].at[chunk_pages, :, off].set(
         kq.astype(cache["k"].dtype))
-    new_cache["v"] = cache["v"].at[chunk_pages, off].set(
+    new_cache["v"] = cache["v"].at[chunk_pages, :, off].set(
         vq.astype(cache["v"].dtype))
 
     if use_paged_kernel():
@@ -535,17 +581,8 @@ def attn_prefill_chunk_paged(p, x, pos0, cache, block_tables, chunk_pages, *,
         return y, new_cache
 
     _count_paged_backend("prefill_oracle")
-    ka = new_cache["k"][block_tables].reshape(B, MP * ps, num_kv_heads,
-                                              head_dim)
-    va = new_cache["v"][block_tables].reshape(B, MP * ps, num_kv_heads,
-                                              head_dim)
-    if quant:
-        ksa = new_cache["k_s"][block_tables].reshape(B, MP * ps, num_kv_heads)
-        vsa = new_cache["v_s"][block_tables].reshape(B, MP * ps, num_kv_heads)
-        ka = _dequantize_kv(ka, ksa, x.dtype)
-        va = _dequantize_kv(va, vsa, x.dtype)
-
-    kv_pos = jnp.arange(MP * ps)
+    ka, va = _gather_pages(new_cache, block_tables, x.dtype)
+    kv_pos = jnp.arange(ka.shape[1])
     mask = kv_pos[None, None, :] <= qpos[:, :, None]           # (B, C, S)
     mask = mask[:, None, None]
 
@@ -609,7 +646,7 @@ def attn_decode_paged(p, x, pos, cache, block_tables, write_pages=None, *,
     """One-token decode against a paged KV pool (global layers only).
 
     x: (B, 1, d); pos: (B,) int32 per-row positions; cache: page pool from
-    :func:`init_paged_kv` with leaves (P, ps, KV, hd); block_tables:
+    :func:`init_paged_kv` with leaves (P, KV, ps, hd); block_tables:
     (B, MP) int32 mapping row-logical pages to physical pages (unowned
     entries alias the trash page — validity is purely ``kv_pos <= pos``).
 
@@ -632,8 +669,7 @@ def attn_decode_paged(p, x, pos, cache, block_tables, write_pages=None, *,
         q = apply_rope(q, pos[:, None], rope_theta)
         k = apply_rope(k, pos[:, None], rope_theta)
 
-    ps = cache["k"].shape[1]
-    MP = block_tables.shape[1]
+    ps = cache["k"].shape[2]
     lpage = pos // ps
     off = pos % ps
     if write_pages is None:
@@ -646,12 +682,14 @@ def attn_decode_paged(p, x, pos, cache, block_tables, write_pages=None, *,
     if quant:
         kq, ks = _quantize_kv(k)
         vq, vs = _quantize_kv(v)
-        new_cache["k_s"] = cache["k_s"].at[phys, off].set(ks[:, 0])
-        new_cache["v_s"] = cache["v_s"].at[phys, off].set(vs[:, 0])
+        new_cache["k_s"] = cache["k_s"].at[phys, :, 0, off].set(ks[:, 0])
+        new_cache["v_s"] = cache["v_s"].at[phys, :, 0, off].set(vs[:, 0])
     else:
         kq, vq = k, v
-    new_cache["k"] = cache["k"].at[phys, off].set(kq[:, 0].astype(cache["k"].dtype))
-    new_cache["v"] = cache["v"].at[phys, off].set(vq[:, 0].astype(cache["v"].dtype))
+    new_cache["k"] = cache["k"].at[phys, :, off].set(
+        kq[:, 0].astype(cache["k"].dtype))
+    new_cache["v"] = cache["v"].at[phys, :, off].set(
+        vq[:, 0].astype(cache["v"].dtype))
 
     if use_paged_kernel():
         # paged flash-decode kernel: the S-tile index map dereferences the
@@ -669,16 +707,8 @@ def attn_decode_paged(p, x, pos, cache, block_tables, write_pages=None, *,
         return y, new_cache
 
     _count_paged_backend("decode_oracle")
-    # gather the row's pages into its contiguous logical sequence view
-    ka = new_cache["k"][block_tables].reshape(B, MP * ps, num_kv_heads, head_dim)
-    va = new_cache["v"][block_tables].reshape(B, MP * ps, num_kv_heads, head_dim)
-    if quant:
-        ksa = new_cache["k_s"][block_tables].reshape(B, MP * ps, num_kv_heads)
-        vsa = new_cache["v_s"][block_tables].reshape(B, MP * ps, num_kv_heads)
-        ka = _dequantize_kv(ka, ksa, x.dtype)
-        va = _dequantize_kv(va, vsa, x.dtype)
-
-    kv_positions = jnp.arange(MP * ps)
+    ka, va = _gather_pages(new_cache, block_tables, x.dtype)
+    kv_positions = jnp.arange(ka.shape[1])
     valid = kv_positions[None, :] <= pos[:, None]               # (B, S)
 
     qr = q.reshape(B, 1, num_kv_heads, G, head_dim)
@@ -759,16 +789,9 @@ def attn_forward_halo(p, x, *, num_heads: int, num_kv_heads: int,
         return y, k, v
 
     xspec = P(dp if dp else None, model_axis, None)
-    try:
-        from jax import shard_map as _sm
-        f = _sm(inner, mesh=mesh, check_vma=False,
-                in_specs=(P(), P(), P(), P(), P(), P(), P(), xspec),
-                out_specs=(xspec, xspec, xspec))
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm2
-        f = _sm2(inner, mesh=mesh, check_rep=False,
-                 in_specs=(P(), P(), P(), P(), P(), P(), P(), xspec),
-                 out_specs=(xspec, xspec, xspec))
+    f = jax.shard_map(inner, mesh=mesh, check_vma=False,
+                      in_specs=(P(), P(), P(), P(), P(), P(), P(), xspec),
+                      out_specs=(xspec, xspec, xspec))
     bq, bk, bv = p.get("bq"), p.get("bk"), p.get("bv")
     y, k, v = f(p["wq"], p["wk"], p["wv"], p["wo"], bq, bk, bv, x)
     if return_kv:

@@ -138,20 +138,12 @@ def moe_ffn_expert_parallel(p, x, *, num_experts: int, experts_per_tok: int,
     constraint keeps the stream seq-sharded)."""
     assert _MESH is not None, "call repro.models.moe.set_mesh(mesh) first"
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map as _shard_map
 
-        def shard_map(f, **kw):
-            # check_vma can't statically prove the post-all_gather model-axis
-            # replication of y; disable the check (correctness covered by
-            # scripts/validate_moe_ep.py against the dropless oracle)
-            return _shard_map(f, check_vma=False, **kw)
-    except ImportError:  # older spelling
-        from jax.experimental.shard_map import shard_map as _sm
-
-        def shard_map(f, mesh=None, in_specs=None, out_specs=None):
-            return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                       check_rep=False)
+    def shard_map(f, **kw):
+        # check_vma can't statically prove the post-all_gather model-axis
+        # replication of y; disable the check (correctness covered by
+        # scripts/validate_moe_ep.py against the dropless oracle)
+        return jax.shard_map(f, check_vma=False, **kw)
 
     E, K = num_experts, experts_per_tok
     dp = tuple(a for a in dp_axes if a in _MESH.axis_names)

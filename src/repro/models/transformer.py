@@ -495,12 +495,12 @@ def init_paged_cache(cfg: ModelConfig, batch: int, num_pages: int,
                      page_size: int, max_seq: int):
     """Paged variant of :func:`init_cache` (DESIGN.md §5).
 
-    Global-attention layers share a page pool — their leaves get shape
-    (num_pages + 1, page_size, KV, hd), where physical page ``num_pages``
-    is the shared *trash* page that unowned block-table entries alias.
-    With ``cfg.kv_cache_dtype == "int8"`` the K/V leaves are int8 and
-    per-token-head fp32 scale leaves ``k_s``/``v_s`` of shape
-    (num_pages + 1, page_size, KV) ride alongside — page-granular, so
+    Global-attention layers share a page pool — their leaves get the
+    head-major shape (num_pages + 1, KV, page_size, hd), where physical
+    page ``num_pages`` is the shared *trash* page that unowned block-table
+    entries alias. With ``cfg.kv_cache_dtype == "int8"`` the K/V leaves
+    are int8 and per-token-head fp32 scale leaves ``k_s``/``v_s`` of shape
+    (num_pages + 1, KV, 1, page_size) ride alongside — page-granular, so
     they follow the same block table through COW copies, prefix sharing,
     and the Pallas kernels' scalar-prefetched index maps (DESIGN.md
     §13). Every other leaf family (sliding-window ring caches,

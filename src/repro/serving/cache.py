@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.models import attention as attn
+
 
 def _map_batched(cache: Dict[str, Any], fn_stack, fn_rem):
     out = {}
@@ -545,6 +547,17 @@ def _map_layer_entries(cfg, cache: Dict[str, Any], other: Dict[str, Any],
     return out
 
 
+def _to_pages(name: str, b, page_size: int):
+    """A contiguous sub-cache leaf (..., n, S, KV[, hd]) of a global layer
+    cut into the pool's head-major pages, rows flattened:
+    (..., n*S/ps, KV, ps, hd) values or (..., n*S/ps, KV, 1, ps) int8
+    scales (``k_s``/``v_s``)."""
+    scale = name in ("k_s", "v_s")
+    seq_ax = b.ndim - (2 if scale else 3)
+    flat = b.reshape(b.shape[:seq_ax - 1] + (-1,) + b.shape[seq_ax + 1:])
+    return attn.pages_from_seq(flat, page_size, scale=scale)
+
+
 def install_paged(cfg, pool, row_idx, phys_flat, sub, page_size: int):
     """Install a freshly prefilled contiguous sub-cache into the paged
     pool — the paged analogue of :func:`scatter_batch`.
@@ -553,21 +566,17 @@ def install_paged(cfg, pool, row_idx, phys_flat, sub, page_size: int):
     ``phys_flat``: (n * max_pages,) physical page per (row, logical page),
     trash-aliased for unowned logical pages. Global-attention leaves
     scatter page-wise (the sub-cache's sequence axis is reshaped to
-    (max_pages, page_size) and written through the page list; duplicate
-    trash writes are garbage-on-garbage). Every per-row leaf family
-    (ring, recurrent, rwkv6, cross-KV) scatters into the row slots."""
+    (max_pages, page_size) head-major pages and written through the page
+    list; duplicate trash writes are garbage-on-garbage). Every per-row
+    leaf family (ring, recurrent, rwkv6, cross-KV) scatters into the row
+    slots."""
     def per_entry(bt, is_stack, entry, sub_entry):
         if bt == "global":
-            def leaf(a, b):
-                if is_stack:           # a: (K, P+1, ps, ...), b: (K, n, S, ...)
-                    K, n, S = b.shape[0], b.shape[1], b.shape[2]
-                    br = b.reshape((K, n * (S // page_size), page_size)
-                                   + b.shape[3:])
-                    return a.at[:, phys_flat].set(br.astype(a.dtype))
-                n, S = b.shape[0], b.shape[1]
-                br = b.reshape((n * (S // page_size), page_size) + b.shape[2:])
-                return a.at[phys_flat].set(br.astype(a.dtype))
-            return jax.tree.map(leaf, entry, sub_entry)
+            def leaf(name, a):
+                br = _to_pages(name, sub_entry[name], page_size)
+                return (a.at[:, phys_flat] if is_stack
+                        else a.at[phys_flat]).set(br.astype(a.dtype))
+            return {name: leaf(name, a) for name, a in entry.items()}
         def leaf_row(a, b):
             return a.at[:, row_idx].set(b) if is_stack else a.at[row_idx].set(b)
         return jax.tree.map(leaf_row, entry, sub_entry)
@@ -586,22 +595,18 @@ def install_paged_shared(cfg, pool, row_idx, src_idx, phys, sub1,
     appear ONCE (all n branch block tables alias them); the partially-
     written boundary page at ``prompt_len % page_size`` appears once per
     branch, so each branch gets a private copy-on-write copy to receive
-    its divergent decode writes. Global leaves scatter the reshaped
-    (max_pages, page_size) prefill through that (src, phys) map; every
+    its divergent decode writes. Global leaves scatter the prefill, cut
+    into head-major pages, through that (src, phys) map; every
     per-row leaf family (ring, recurrent, rwkv6, cross-KV) broadcasts the
     batch-1 state into the n row slots."""
     def per_entry(bt, is_stack, entry, sub_entry):
         if bt == "global":
-            def leaf(a, b):
-                if is_stack:           # a: (K, P+1, ps, ...), b: (K, 1, S, ...)
-                    K, S = b.shape[0], b.shape[2]
-                    br = b[:, 0].reshape((K, S // page_size, page_size)
-                                         + b.shape[3:])
+            def leaf(name, a):
+                br = _to_pages(name, sub_entry[name], page_size)
+                if is_stack:
                     return a.at[:, phys].set(br[:, src_idx].astype(a.dtype))
-                S = b.shape[1]
-                br = b[0].reshape((S // page_size, page_size) + b.shape[2:])
                 return a.at[phys].set(br[src_idx].astype(a.dtype))
-            return jax.tree.map(leaf, entry, sub_entry)
+            return {name: leaf(name, a) for name, a in entry.items()}
 
         def leaf_row(a, b):            # b batch-1, broadcast over row_idx
             return a.at[:, row_idx].set(b) if is_stack else a.at[row_idx].set(b)

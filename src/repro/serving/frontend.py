@@ -25,6 +25,12 @@ the token sequence batch ``run()`` produces on the same seed — the
 committed-prefix emission rule guarantees every streamed prefix is a
 prefix of the final ``GenResult.tokens``, and the terminal event flushes
 the rest.
+
+A tick that raises (a device program that fails to compile or run, a
+scheduler bug) stops the tick loop and fails the front-end: the error
+is re-raised from every waiter — ``drain()``, ``join()``, ``events()`` /
+``stream()``, ``result()`` / ``wait_result()`` and later submissions —
+instead of leaving them to wait on work no tick will ever finish.
 """
 from __future__ import annotations
 
@@ -35,6 +41,9 @@ import time
 from typing import AsyncIterator, Dict, Iterator, List, Optional
 
 from .scheduler import GenResult, TokenEvent
+
+# wakes a consumer blocked on its event channel when the tick loop fails
+_FAILED = object()
 
 
 class ServingFrontend:
@@ -64,6 +73,7 @@ class ServingFrontend:
         self._task: Optional[asyncio.Task] = None
         self._thread: Optional[threading.Thread] = None
         self._loop: Optional[asyncio.AbstractEventLoop] = None
+        self._error: Optional[BaseException] = None   # a failed tick
 
     # ------------------------------------------------------------ lifecycle
 
@@ -98,21 +108,25 @@ class ServingFrontend:
 
     async def aclose(self) -> None:
         """Drain all in-flight work, then stop the tick task."""
-        await self.drain()
-        self._stop = True
-        if self._task is not None:
-            await self._task
-            self._task = None
-        self._shutdown()
+        try:
+            await self.drain()
+        finally:
+            self._stop = True
+            if self._task is not None:
+                await self._task
+                self._task = None
+            self._shutdown()
 
     def close(self) -> None:
         """Thread-backend twin of :meth:`aclose`."""
-        self.join()
-        self._stop = True
-        if self._thread is not None:
-            self._thread.join()
-            self._thread = None
-        self._shutdown()
+        try:
+            self.join()
+        finally:
+            self._stop = True
+            if self._thread is not None:
+                self._thread.join()
+                self._thread = None
+            self._shutdown()
 
     def _shutdown(self) -> None:
         with self._lock:
@@ -132,9 +146,29 @@ class ServingFrontend:
                 self.slo.on_tick()
             return True
 
+    def _fail(self, err: BaseException) -> None:
+        """Record a failed tick and wake every waiter to re-raise it."""
+        with self._lock:
+            self._error = err
+            for fut in self._futures.values():
+                if not fut.done():
+                    fut.set_exception(err)
+            self._futures.clear()
+            for ch in self._chan.values():
+                ch.put_nowait(_FAILED)
+            self._done_cv.notify_all()
+
+    def _check_failed(self) -> None:
+        if self._error is not None:
+            raise self._error
+
     async def _tick_loop_async(self) -> None:
         while not self._stop:
-            worked = self._tick_once()
+            try:
+                worked = self._tick_once()
+            except Exception as e:
+                self._fail(e)
+                return
             # sleep(0) after a working tick: consumers woken by this
             # tick's put_nowait calls were queued on the loop BEFORE
             # this continuation, so they all run before the next tick —
@@ -143,7 +177,12 @@ class ServingFrontend:
 
     def _tick_loop_thread(self) -> None:
         while not self._stop:
-            if not self._tick_once():
+            try:
+                worked = self._tick_once()
+            except Exception as e:
+                self._fail(e)
+                return
+            if not worked:
                 # idle pacing of a live OS thread: wall-clock by nature,
                 # never observable in tokens (replay is RNG-driven)
                 # repro-lint: disable-next-line=replay-determinism
@@ -175,6 +214,7 @@ class ServingFrontend:
         rid's event channel is registered under the same lock as the
         submit, so even a synchronous SHED terminal event is captured."""
         with self._lock:
+            self._check_failed()
             rid = self.sched.submit(prompt, rng, **kw)
             ch = self._new_channel()
             self._chan[rid] = ch
@@ -209,6 +249,8 @@ class ServingFrontend:
         try:
             while True:
                 ev = await ch.get()
+                if ev is _FAILED:
+                    self._check_failed()
                 yield ev
                 if ev.kind == "end":
                     return
@@ -223,6 +265,8 @@ class ServingFrontend:
         try:
             while True:
                 ev = ch.get(timeout=timeout)
+                if ev is _FAILED:
+                    self._check_failed()
                 yield ev
                 if ev.kind == "end":
                     return
@@ -236,6 +280,7 @@ class ServingFrontend:
             res = self.sched.results.get(rid)
             if res is not None:
                 return res
+            self._check_failed()
             fut = self._futures.get(rid)
             if fut is None:
                 fut = self._loop.create_future()
@@ -247,8 +292,11 @@ class ServingFrontend:
         """Sync twin of :meth:`result` for the thread backend."""
         with self._done_cv:
             if not self._done_cv.wait_for(
-                    lambda: rid in self.sched.results, timeout):
+                    lambda: rid in self.sched.results
+                    or self._error is not None, timeout):
                 raise TimeoutError(f"rid {rid} not terminal in {timeout}s")
+            if rid not in self.sched.results:
+                self._check_failed()
             return self.sched.results[rid]
 
     def cancel(self, rid: int) -> None:
@@ -264,6 +312,7 @@ class ServingFrontend:
         work (all submitted requests reached a terminal event)."""
         while True:
             with self._lock:
+                self._check_failed()
                 if not self.sched.has_work:
                     return
             await asyncio.sleep(0)
@@ -279,6 +328,7 @@ class ServingFrontend:
             deadline = time.monotonic() + timeout_s
         while True:
             with self._lock:
+                self._check_failed()
                 if not self.sched.has_work:
                     return
             # repro-lint: disable-next-line=replay-determinism

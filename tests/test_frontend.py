@@ -7,6 +7,7 @@ shutdown. No pytest-asyncio: each async scenario runs under
 ``asyncio.run`` inside a sync test."""
 import asyncio
 import dataclasses
+import threading
 
 import jax
 import numpy as np
@@ -251,3 +252,88 @@ def test_shutdown_drains_zero_leaks(setup):
     assert all(r.status == "OK" for r in sched.results.values())
     assert sched.event_sink is None      # frontend detached cleanly
     _assert_no_leaks(sched)
+
+
+# ------------------------------------------------------- failed tick
+
+def _fail_after(monkeypatch, ticks: int):
+    """Make every PagedScheduler tick past ``ticks`` raise."""
+    real_step = PagedScheduler.step
+
+    def step(self):
+        if self.ticks >= ticks:
+            raise RuntimeError("tick failed")
+        return real_step(self)
+
+    monkeypatch.setattr(PagedScheduler, "step", step)
+
+
+def test_failed_tick_fails_frontend_serve(monkeypatch):
+    """A tick that raises fails the streaming serve within seconds
+    instead of hanging it: every stream, and the drain on exit, re-raise
+    the tick's error. The serve runs on a daemon thread so that a
+    regression fails this test rather than hanging the suite."""
+    from repro.launch.serve import serve_eval
+    _fail_after(monkeypatch, 3)
+    raised = []
+
+    def serve():
+        try:
+            serve_eval("deepseek-r1-distill-qwen-1.5b", "kappa", n=2,
+                       problems=2, max_new=8, d_model=64, paged=True,
+                       page_size=8, prefill_chunk=4, frontend_serve=True,
+                       stream=True, verbose=False)
+        except Exception as e:
+            raised.append(e)
+
+    t = threading.Thread(target=serve, daemon=True)
+    t.start()
+    t.join(timeout=120)
+    assert not t.is_alive(), "frontend serve hung on a failed tick"
+    assert len(raised) == 1 and isinstance(raised[0], RuntimeError)
+    assert "tick failed" in str(raised[0])
+
+
+def test_failed_tick_fails_thread_backend(setup, monkeypatch):
+    """Thread backend twin: the blocked stream, the result wait, join()
+    and close() re-raise the error, and later submissions refuse."""
+    _, _, _, prompts = setup
+    sched = _mk(setup, "paged")
+    _fail_after(monkeypatch, 2)
+    fe = ServingFrontend(sched)
+    fe.start_thread()
+    rid = fe.submit_nowait(prompts[0], jax.random.PRNGKey(0))
+    with pytest.raises(RuntimeError, match="tick failed"):
+        list(fe.stream(rid, timeout=60))
+    with pytest.raises(RuntimeError, match="tick failed"):
+        fe.wait_result(rid, timeout=60)
+    with pytest.raises(RuntimeError, match="tick failed"):
+        fe.submit_nowait(prompts[1], jax.random.PRNGKey(1))
+    with pytest.raises(RuntimeError, match="tick failed"):
+        fe.join(timeout_s=60)
+    with pytest.raises(RuntimeError, match="tick failed"):
+        fe.close()
+    assert sched.event_sink is None
+
+
+# ------------------------------------------------------- full width
+
+def test_full_width_serves_config_as_published(monkeypatch):
+    """``full_width`` serves the config itself — its layer count, widths
+    and dtype — where the default cuts it to the 2-layer float32 toy."""
+    from repro.launch import serve as serve_mod
+    small = dataclasses.replace(
+        get_config("deepseek-r1-distill-qwen-1.5b"), num_layers=3,
+        d_model=96, num_heads=6, num_kv_heads=2, d_ff=128, vocab_size=512)
+    monkeypatch.setattr(serve_mod, "get_config", lambda arch: small)
+    kw = dict(n=2, problems=2, max_new=6, paged=True, page_size=8,
+              prefill_chunk=4, frontend_serve=True, stream=True,
+              verbose=False)
+    out = serve_mod.serve_eval(small.name, "kappa", full_width=True, **kw)
+    assert out["model"] == {
+        "layers": 3, "d_model": 96, "heads": 6, "kv_heads": 2,
+        "head_dim": 16, "d_ff": 128, "vocab": 512, "dtype": "bfloat16",
+        "kv_cache_dtype": "model"}
+    assert out["status_counts"] == {"OK": 2}
+    toy = serve_mod.serve_eval(small.name, "kappa", **kw)["model"]
+    assert (toy["layers"], toy["dtype"]) == (2, "float32")

@@ -91,8 +91,8 @@ def test_paged_decode_attn_sweep(B, H, KV, hd, ps, MP, P, dtype):
     rng = np.random.RandomState(B * H + ps)
     ks = jax.random.split(jax.random.PRNGKey(hash((B, H, ps, MP)) % 2**31), 3)
     q = jax.random.normal(ks[0], (B, H, hd)).astype(dtype)
-    kp = jax.random.normal(ks[1], (P, ps, KV, hd)).astype(dtype)
-    vp = jax.random.normal(ks[2], (P, ps, KV, hd)).astype(dtype)
+    kp = jax.random.normal(ks[1], (P, KV, ps, hd)).astype(dtype)
+    vp = jax.random.normal(ks[2], (P, KV, ps, hd)).astype(dtype)
     # each row: random position, owned pages drawn without replacement,
     # unowned entries alias the last physical page (trash convention)
     pos = rng.randint(0, MP * ps, size=B).astype(np.int32)
@@ -108,12 +108,13 @@ def test_paged_decode_attn_sweep(B, H, KV, hd, ps, MP, P, dtype):
 
 
 def _quantize_pages(x):
-    """Per-(page, slot, kv-head) absmax int8 quantization — the same
+    """Per-(page, kv-head, slot) absmax int8 quantization of head-major
+    (P, KV, ps, hd) pages; scales come back (P, KV, 1, ps) — the same
     layout the serving cache uses for its ``k_s``/``v_s`` scale leaves."""
     x = np.asarray(x, np.float32)
     s = np.maximum(np.abs(x).max(axis=-1), 1e-8) / 127.0
     q = np.clip(np.round(x / s[..., None]), -127, 127).astype(np.int8)
-    return jnp.asarray(q), jnp.asarray(s.astype(np.float32))
+    return jnp.asarray(q), jnp.asarray(s[:, :, None, :].astype(np.float32))
 
 
 def _scrambled_tables(rng, B, MP, P, ps, pos):
@@ -139,8 +140,8 @@ def test_paged_decode_attn_int8_sweep(B, H, KV, hd, ps, MP, P):
     rng = np.random.RandomState(B * H + ps + 1)
     ks = jax.random.split(jax.random.PRNGKey(hash((B, H, ps, MP, 8)) % 2**31), 3)
     q = jax.random.normal(ks[0], (B, H, hd))
-    kp = jax.random.normal(ks[1], (P, ps, KV, hd))
-    vp = jax.random.normal(ks[2], (P, ps, KV, hd))
+    kp = jax.random.normal(ks[1], (P, KV, ps, hd))
+    vp = jax.random.normal(ks[2], (P, KV, ps, hd))
     kq, ksc = _quantize_pages(kp)
     vq, vsc = _quantize_pages(vp)
     pos = rng.randint(0, MP * ps, size=B).astype(np.int32)
@@ -170,8 +171,8 @@ def test_paged_prefill_attn_sweep(B, C, H, KV, hd, ps, MP, P, quant):
     rng = np.random.RandomState(B * C + ps)
     ks = jax.random.split(jax.random.PRNGKey(hash((B, C, H, ps)) % 2**31), 3)
     q = jax.random.normal(ks[0], (B, C, H, hd))
-    kp = jax.random.normal(ks[1], (P, ps, KV, hd))
-    vp = jax.random.normal(ks[2], (P, ps, KV, hd))
+    kp = jax.random.normal(ks[1], (P, KV, ps, hd))
+    vp = jax.random.normal(ks[2], (P, KV, ps, hd))
     # pos0 = position of the chunk's FIRST token; last token must fit
     pos0 = rng.randint(0, MP * ps - C + 1, size=B).astype(np.int32)
     last = pos0 + C - 1
@@ -197,8 +198,8 @@ def test_paged_prefill_single_token_matches_decode():
     rng = np.random.RandomState(0)
     ks = jax.random.split(jax.random.PRNGKey(11), 3)
     q = jax.random.normal(ks[0], (B, H, hd))
-    kp = jax.random.normal(ks[1], (P, ps, KV, hd))
-    vp = jax.random.normal(ks[2], (P, ps, KV, hd))
+    kp = jax.random.normal(ks[1], (P, KV, ps, hd))
+    vp = jax.random.normal(ks[2], (P, KV, ps, hd))
     pos = rng.randint(0, MP * ps, size=B).astype(np.int32)
     bt = _scrambled_tables(rng, B, MP, P, ps, pos)
     d = paged_decode_attn(q, kp, vp, jnp.asarray(bt), jnp.asarray(pos))
@@ -214,15 +215,17 @@ def test_paged_decode_attn_matches_contiguous_kernel():
     B, H, KV, hd, ps, MP, P = 3, 8, 2, 64, 16, 4, 14
     ks = jax.random.split(jax.random.PRNGKey(3), 3)
     q = jax.random.normal(ks[0], (B, H, hd))
-    kp = jax.random.normal(ks[1], (P, ps, KV, hd))
-    vp = jax.random.normal(ks[2], (P, ps, KV, hd))
+    kp = jax.random.normal(ks[1], (P, KV, ps, hd))
+    vp = jax.random.normal(ks[2], (P, KV, ps, hd))
     rng = np.random.RandomState(1)
     bt = np.stack([rng.choice(P, size=MP, replace=False) for _ in range(B)])
     pos = np.array([5, 63, 40], np.int32)
     out = paged_decode_attn(q, kp, vp, jnp.asarray(bt), jnp.asarray(pos))
     for b in range(B):
-        kc = kp[jnp.asarray(bt[b])].reshape(1, MP * ps, KV, hd)
-        vc = vp[jnp.asarray(bt[b])].reshape(1, MP * ps, KV, hd)
+        kc = kp[jnp.asarray(bt[b])].transpose(0, 2, 1, 3).reshape(
+            1, MP * ps, KV, hd)
+        vc = vp[jnp.asarray(bt[b])].transpose(0, 2, 1, 3).reshape(
+            1, MP * ps, KV, hd)
         oc = decode_attn(q[b:b + 1], kc, vc, int(pos[b]))
         np.testing.assert_allclose(np.asarray(out[b:b + 1]), np.asarray(oc),
                                    rtol=2e-5, atol=2e-5)

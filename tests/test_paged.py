@@ -768,12 +768,15 @@ def test_int8_scale_leaves_ride_cow_paths(setup):
             continue                    # per-row aux scales (ring layers)
         assert a.dtype == jnp.float32, f"{key} truncated to {a.dtype}"
         b = np.asarray(sub[key])
+        # pool scale pages are head-major (..., P, KV, 1, ps)
         if ax == 0:                     # b: (1, S, KV)
             br = b[0].reshape((b.shape[1] // ps, ps) + b.shape[2:])
+            br = br.transpose(0, 2, 1)[:, :, None, :]
             got, want = np.asarray(a)[phys], br[src_idx]
         else:                           # stacked, b: (K, 1, S, KV)
             br = b[:, 0].reshape((b.shape[0], b.shape[2] // ps, ps)
                                  + b.shape[3:])
+            br = br.transpose(0, 1, 3, 2)[:, :, :, None, :]
             got, want = np.asarray(a)[:, phys], br[:, src_idx]
         assert np.array_equal(got, want), f"{key} scales mangled"
         checked += 1
