@@ -56,9 +56,11 @@ holds it within 1.5x; goodput-under-SLO per rate lands in
 BENCH_throughput.json. ``--openloop-smoke`` runs a two-rate reduced
 sweep on an untrained toy model (curve produced + zero leaks) for CI.
 
-Each scheduler run also reports a per-tick wall-time breakdown (model
-step / sampler dispatch / pooled-controller dispatch / blocking sync /
-per-request host work) so controller-overhead regressions are visible:
+Each scheduler run also reports a per-tick wall-time breakdown by tick
+phase (``serving/spans.py``: admission, prefill, page growth, step
+dispatch, sampler keys and their transfer, sampler dispatch,
+pooled-controller dispatch, blocking sync, per-request host work) so
+controller-overhead regressions are visible:
 in the ``pr1`` mode every kappa request pays its own controller dispatch
 + host sync inside the advance loop (it shows up as ``host`` time),
 while the fused modes run ONE pooled controller dispatch per tick —
@@ -122,8 +124,8 @@ INTERLEAVE_LONG = 1536          # long-prompt target length (tokens): the
 INTERLEAVE_REPS = 3             # best-of-R (CPU wall-clock noise; rep 1
                                 # also absorbs jit compiles)
 
-BREAKDOWN_KEYS = ("model", "prefill", "sampler", "controller", "sync",
-                  "host")
+BREAKDOWN_KEYS = ("admit", "prefill", "pages", "step", "keys",
+                  "keys_wait", "sample", "control", "sync", "host")
 
 
 def _tick_breakdown_us(tp):
@@ -1066,7 +1068,7 @@ def emit_csv(rows):
                        f"page_util={r['page_utilization']:.2f};"
                        f"pr1_host_us={bd1['host']:.0f};"
                        f"paged_host_us={bdp['host']:.0f};"
-                       f"paged_ctrl_us={bdp['controller']:.0f}")
+                       f"paged_ctrl_us={bdp['control']:.0f}")
         out.append(f"{name},{us:.1f},{derived}")
     return out
 
@@ -1120,7 +1122,7 @@ if __name__ == "__main__":
             bd1, bdp = r["pr1_tick_breakdown_us"], r["paged_tick_breakdown_us"]
             print(f"# kappa depth={r['depth']}: per-tick controller cost "
                   f"{bd1['host']:.0f}us host (pr1: one dispatch+sync per "
-                  f"request) -> {bdp['controller']:.0f}us pooled dispatch + "
+                  f"request) -> {bdp['control']:.0f}us pooled dispatch + "
                   f"{bdp['host']:.0f}us host "
                   f"({r['paged_controller_dispatches']} dispatches / "
                   f"{r['paged_ticks']} ticks)")

@@ -172,8 +172,10 @@ class ServingFrontend:
             # sleep(0) after a working tick: consumers woken by this
             # tick's put_nowait calls were queued on the loop BEFORE
             # this continuation, so they all run before the next tick —
-            # deterministic stream/tick interleaving without threads
-            await asyncio.sleep(0 if worked else self.idle_sleep_s)
+            # deterministic stream/tick interleaving without threads.
+            # Their host time is the "frontend" phase.
+            with self.sched.tick_time.span("frontend"):
+                await asyncio.sleep(0 if worked else self.idle_sleep_s)
 
     def _tick_loop_thread(self) -> None:
         while not self._stop:
@@ -184,9 +186,12 @@ class ServingFrontend:
                 return
             if not worked:
                 # idle pacing of a live OS thread: wall-clock by nature,
-                # never observable in tokens (replay is RNG-driven)
-                # repro-lint: disable-next-line=replay-determinism
-                time.sleep(self.idle_sleep_s)
+                # never observable in tokens (replay is RNG-driven).
+                # Consumers run on their own threads, so this pause is
+                # the loop's only host time outside a tick.
+                with self.sched.tick_time.span("frontend"):
+                    # repro-lint: disable-next-line=replay-determinism
+                    time.sleep(self.idle_sleep_s)
 
     # ------------------------------------------------------------- events
 

@@ -50,8 +50,9 @@ Shared driver behaviour per tick:
     tick's single blocking transfer — replacing the per-request
     ``kappa_step`` dispatch + ``np.asarray(alive)`` sync that made the
     controller the bottleneck (dispatch/sync counters in ``counters``
-    assert the ≤1-per-tick contract; ``tick_time`` records the per-tick
-    model/sampler/controller/sync/host breakdown);
+    assert the ≤1-per-tick contract; ``tick_time`` records host seconds
+    per tick phase, each also a ``serve.<phase>`` profiler span —
+    ``serving/spans.py``);
   * per-request strategies (repro.serving.strategies) drive pruning and
     compaction decisions on their own row groups (host-side, from the
     published controller mirrors); freed capacity is backfilled by
@@ -109,6 +110,7 @@ from repro.serving import cache as cache_lib
 from repro.serving import engine
 from repro.serving import faults as faults_lib
 from repro.serving import sampler
+from repro.serving import spans
 from repro.serving import strategies
 from repro.serving.strategies import GenResult
 
@@ -269,11 +271,10 @@ class _SchedulerBase:
         self.max_queue = max_queue
         self._fault_tick = False     # an alloc embargo is live this tick
         self._has_deadlines = False  # sticky: any submit set a deadline
-        # per-tick wall-time breakdown (seconds, cumulative over run)
-        self.tick_time: Dict[str, float] = {
-            "model": 0.0, "prefill": 0.0, "sampler": 0.0,
-            "controller": 0.0, "sync": 0.0, "host": 0.0,
-        }
+        # host seconds per tick phase, cumulative over the run; each
+        # phase is also a ``serve.<phase>`` span in a profiler trace
+        self.tick_time = spans.PhaseTimes()
+        spans.watch_gc()
         # admission-side peak: bytes of the largest transient prefill
         # structure (prompt-sized side cache / chunked aux state) — the
         # regression knob for the old max_seq-sized throwaway cache
@@ -281,8 +282,8 @@ class _SchedulerBase:
         # injectable monotonic clock: every user-visible latency read
         # (submit stamps, deadlines, TTFT/ITL, run elapsed) goes through
         # it so tests advance time without sleeping. The tick_time
-        # profiling breakdown keeps real perf_counter deltas — it
-        # measures compute cost, not request-visible latency.
+        # phase spans keep the real host clock — they measure compute
+        # cost, not request-visible latency.
         self.clock: Callable[[], float] = clock or time.monotonic
         # latency bookkeeping: submit walltime, time-to-first-token and
         # per-tick token emission stamps (ITL = consecutive diffs)
@@ -763,14 +764,12 @@ class _SchedulerBase:
         decode step exactly like a one-shot admission would. Fuse
         candidates are skipped here — their chunks run inside the decode
         dispatch and complete in ``_post_tick_prefill``."""
-        t0 = time.perf_counter()
         self._fused_rids = self._fuse_candidates()
         fused = set(self._fused_rids)
         for rid in sorted(list(self.prefilling),
                           key=lambda r: self._admit_seq[r]):
             if rid not in fused:
                 self._advance_one_prefill(rid)
-        self.tick_time["prefill"] += time.perf_counter() - t0
 
     def _post_tick_prefill(self) -> None:
         """Finalize a fused chunk that completed its prompt this tick
@@ -844,112 +843,126 @@ class _SchedulerBase:
         controller outputs, then advance every active request on its own
         rows (pure host work). Decode rows therefore never wait for a
         whole admission prefill — at most one chunk of it runs inside
-        their tick."""
-        self._watchdog()
-        self._fault_tick = self._begin_fault_tick()
-        self._admit_left = self.prefill_budget
-        while self._admit_one():
-            pass
-        self._advance_prefills()
-        if not self.active:
-            # pure-backoff and embargo-blocked ticks still count as
-            # progress: the tick index must advance for `not_before`
-            # stamps to expire and for the next tick's fault draw
-            progressed = bool(self.prefilling) \
-                or any(i.not_before > self.ticks for i in self.queue) \
-                or (self._fault_tick and bool(self.queue)) \
-                or (self.admit_paused and bool(self.queue)) \
-                or (self._admit_left is not None and self._admit_left <= 0
-                    and bool(self.queue))
-            if self._fused_rids:
-                # the decode dispatch these chunks were to ride vanished
-                # (a sibling's page growth preempted the whole pool) —
-                # run them standalone so no prefill loses its turn
-                rids, self._fused_rids = self._fused_rids, []
-                for rid in rids:
-                    self._advance_one_prefill(rid)
-            if progressed:
-                # PREFILLING requests hold rows (and, paged, pages) —
-                # account them so utilization metrics stay honest over
-                # chunked-admission-heavy stretches
-                self._occupied_ticks += self.rows - len(self.free)
-                self._account_pages_tick()
-                self.ticks += 1
-            return
-        self._occupied_ticks += self.rows - len(self.free)
+        their tick. Each phase runs in its ``tick_time`` span."""
+        tt = self.tick_time
+        with tt.span("tick", step=self.ticks):
+            with tt.span("admit"):
+                self._watchdog()
+                self._fault_tick = self._begin_fault_tick()
+                self._admit_left = self.prefill_budget
+                while self._admit_one():
+                    pass
+            with tt.span("prefill"):
+                self._advance_prefills()
+            if not self.active:
+                # pure-backoff and embargo-blocked ticks still count as
+                # progress: the tick index must advance for `not_before`
+                # stamps to expire and for the next tick's fault draw
+                progressed = bool(self.prefilling) \
+                    or any(i.not_before > self.ticks for i in self.queue) \
+                    or (self._fault_tick and bool(self.queue)) \
+                    or (self.admit_paused and bool(self.queue)) \
+                    or (self._admit_left is not None
+                        and self._admit_left <= 0 and bool(self.queue))
+                if self._fused_rids:
+                    # the decode dispatch these chunks were to ride
+                    # vanished (a sibling's page growth preempted the
+                    # whole pool) — run them standalone so no prefill
+                    # loses its turn
+                    rids, self._fused_rids = self._fused_rids, []
+                    with tt.span("prefill"):
+                        for rid in rids:
+                            self._advance_one_prefill(rid)
+                if progressed:
+                    # PREFILLING requests hold rows (and, paged, pages) —
+                    # account them so utilization metrics stay honest
+                    # over chunked-admission-heavy stretches
+                    self._occupied_ticks += self.rows - len(self.free)
+                    self._account_pages_tick()
+                    self.ticks += 1
+                return
+            self._occupied_ticks += self.rows - len(self.free)
 
-        t0 = time.perf_counter()
-        try:
-            logits = self._decode_tick()
-        except faults_lib.InjectedStepFault:
-            # the injection point is BEFORE any pool/allocator mutation,
-            # so the tick simply didn't happen: tear one victim down
-            # through the retry budget and let everyone else retry
-            self.counters["faults_injected"] += 1
-            self._recover_step_fault()
-            self.tick_time["model"] += time.perf_counter() - t0
-            self.ticks += 1
-            return
-        finite_dev = None
-        if self.faults is not None:
-            bad = self.faults.nan_rows_for(self.ticks, self.rows)
-            if bad.size:
+            try:
+                logits = self._decode_tick()
+            except faults_lib.InjectedStepFault:
+                # the injection point is BEFORE any pool/allocator
+                # mutation, so the tick simply didn't happen: tear one
+                # victim down through the retry budget and let everyone
+                # else retry
                 self.counters["faults_injected"] += 1
-                logits = logits.at[jnp.asarray(bad)].set(jnp.nan)
-            # detection is device-side (a fused finite-mask riding the
-            # tick's blocking transfer), not host knowledge of `bad` —
-            # the same path a real numerics blowup would take
-            finite_dev = engine.rows_finite(logits)
-        t1 = time.perf_counter()
-        self.tick_time["model"] += t1 - t0
+                self._recover_step_fault()
+                self.ticks += 1
+                return
+            finite_dev = None
+            if self.faults is not None:
+                bad = self.faults.nan_rows_for(self.ticks, self.rows)
+                if bad.size:
+                    self.counters["faults_injected"] += 1
+                    logits = logits.at[jnp.asarray(bad)].set(jnp.nan)
+                # detection is device-side (a fused finite-mask riding
+                # the tick's blocking transfer), not host knowledge of
+                # `bad` — the same path a real numerics blowup would take
+                finite_dev = engine.rows_finite(logits)
 
-        toks = picked = finite = None
-        if self.fused_sampling:
-            # one fused per-row-keyed sampling dispatch for the whole
-            # pool; free rows ride along as masked argmax (ignored)
-            keys = np.zeros((self.rows, 2), np.uint32)
-            gmask = np.ones((self.rows,), bool)
-            want_lp = False
-            key_devs = {}
-            for rid, (rs, slots) in self.active.items():
-                key_devs[rid] = rs.step_keys()   # device splits, no sync
-                gmask[slots] = rs.strategy.greedy
-                want_lp |= rs.strategy.wants_picked_lp
-            key_np = jax.device_get(key_devs)    # one blocking transfer
-            self.counters["host_syncs"] += 1
-            for rid, (rs, slots) in self.active.items():
-                keys[slots] = key_np[rid]
-            # picked-token log-probs fused into the sampling dispatch
-            # so BoN-style strategies do zero device work per request
-            out_dev = sampler.sample_rows(
-                jnp.asarray(keys), logits, jnp.asarray(gmask), self.kcfg,
-                want_picked_lp=want_lp)
-            self.counters["sampler_dispatches"] += 1
-            toks_dev = out_dev[0] if want_lp else out_dev
-            t2 = time.perf_counter()
-            self.tick_time["sampler"] += t2 - t1
+            toks = picked = finite = None
+            if self.fused_sampling:
+                # one fused per-row-keyed sampling dispatch for the whole
+                # pool; free rows ride along as masked argmax (ignored)
+                keys = np.zeros((self.rows, 2), np.uint32)
+                gmask = np.ones((self.rows,), bool)
+                want_lp = False
+                key_devs = {}
+                with tt.span("keys"):
+                    for rid, (rs, slots) in self.active.items():
+                        key_devs[rid] = rs.step_keys()   # device splits
+                        gmask[slots] = rs.strategy.greedy
+                        want_lp |= rs.strategy.wants_picked_lp
+                with tt.span("keys_wait"):
+                    key_np = jax.device_get(key_devs)  # blocking transfer
+                self.counters["host_syncs"] += 1
+                with tt.span("sample"):
+                    for rid, (rs, slots) in self.active.items():
+                        keys[slots] = key_np[rid]
+                    # picked-token log-probs fused into the sampling
+                    # dispatch so BoN-style strategies do zero device
+                    # work per request
+                    out_dev = sampler.sample_rows(
+                        jnp.asarray(keys), logits, jnp.asarray(gmask),
+                        self.kcfg, want_picked_lp=want_lp)
+                self.counters["sampler_dispatches"] += 1
+                toks_dev = out_dev[0] if want_lp else out_dev
 
-            # the pooled controller consumes the pool logits and the
-            # just-sampled tokens device-to-device — no host round-trip
-            ctrl_dev = self._pooled_kappa_dispatch(logits, toks_dev)
-            t3 = time.perf_counter()
-            self.tick_time["controller"] += t3 - t2
+                # the pooled controller consumes the pool logits and the
+                # just-sampled tokens device-to-device — no host
+                # round-trip
+                with tt.span("control"):
+                    ctrl_dev = self._pooled_kappa_dispatch(logits, toks_dev)
 
-            # ONE blocking transfer for sampled tokens, picked log-probs
-            # AND all pooled controller outputs (alive/traj/cutoff of
-            # every kappa request), independent of active-request count
-            out, ctrl_host, finite = jax.device_get(
-                (out_dev, ctrl_dev, finite_dev))
-            self.counters["host_syncs"] += 1
-            if ctrl_host is not None:
-                self.counters["controller_syncs"] += 1
-                self._kappa_pool.publish(ctrl_host)
-            toks, picked = out if want_lp else (out, None)
-            self.tick_time["sync"] += time.perf_counter() - t3
-        elif finite_dev is not None:
-            finite = jax.device_get(finite_dev)
+                # ONE blocking transfer for sampled tokens, picked
+                # log-probs AND all pooled controller outputs (alive/
+                # traj/cutoff of every kappa request), independent of
+                # active-request count
+                with tt.span("sync"):
+                    out, ctrl_host, finite = jax.device_get(
+                        (out_dev, ctrl_dev, finite_dev))
+                    self.counters["host_syncs"] += 1
+                    if ctrl_host is not None:
+                        self.counters["controller_syncs"] += 1
+                        self._kappa_pool.publish(ctrl_host)
+                    toks, picked = out if want_lp else (out, None)
+            elif finite_dev is not None:
+                finite = jax.device_get(finite_dev)
 
-        t4 = time.perf_counter()
+            with tt.span("host"):
+                self._advance_rows(logits, toks, picked, finite)
+            self.ticks += 1
+
+    def _advance_rows(self, logits, toks, picked, finite) -> None:
+        """The tick's host work after its blocking transfer: tear down
+        NaN-poisoned rows, advance every active request on its own rows,
+        finalize chunks that completed, and stamp and emit the committed
+        tokens."""
         if finite is not None and not bool(np.all(finite)):
             # NaN-poisoned rows: tear the owning requests down BEFORE
             # the advance loop, so poisoned tokens never reach a token
@@ -991,16 +1004,15 @@ class _SchedulerBase:
                 # check reads the pooled controller mirrors
                 self._finalize(rid, "OK")
         self._post_tick_prefill()
-        now = self.clock()
-        for rid in stamped:
-            times = self.token_times.get(rid)
-            if times is not None:      # absent iff preempted mid-tick
-                self._win_itl.append(now - times[-1])
-                times.append(now)
-            if rid in self.active:     # finalized rids flushed already
-                self._emit_committed(rid, now)
-        self.tick_time["host"] += time.perf_counter() - t4
-        self.ticks += 1
+        with self.tick_time.span("emit"):
+            now = self.clock()
+            for rid in stamped:
+                times = self.token_times.get(rid)
+                if times is not None:      # absent iff preempted mid-tick
+                    self._win_itl.append(now - times[-1])
+                    times.append(now)
+                if rid in self.active:     # finalized rids flushed already
+                    self._emit_committed(rid, now)
 
     # --------------------------------------------------------------- run
 
@@ -1125,10 +1137,12 @@ class _SchedulerBase:
             "row_utilization": (self._occupied_ticks
                                 / max(self.ticks * self.rows, 1)),
         }
-        # per-tick breakdown: model step vs sampler dispatch vs pooled
-        # controller dispatch vs the blocking transfer vs per-request
-        # host work (which absorbs UNPOOLED controller dispatch + sync —
-        # the regression the breakdown exists to make visible)
+        # host seconds per tick phase (serving/spans.py): "step" and
+        # "sample"/"control" time the enqueue of the device programs,
+        # "keys_wait" and "sync" the two blocking transfers, and "host"
+        # the per-request advance loop (which absorbs UNPOOLED
+        # controller dispatch + sync — the regression the breakdown
+        # exists to make visible)
         for k, v in self.tick_time.items():
             out[f"time_{k}_s"] = v
         out.update(self.counters)
@@ -1239,10 +1253,11 @@ class ContinuousBatchingScheduler(_SchedulerBase):
         return True
 
     def _decode_tick(self):
-        engine.check_step_fault(self.faults, self.ticks)
-        logits, self.pool = engine._model_step(
-            self.params, self.cfg, jnp.asarray(self.row_token),
-            jnp.asarray(self.row_pos), self.pool)
+        with self.tick_time.span("step"):
+            engine.check_step_fault(self.faults, self.ticks)
+            logits, self.pool = engine._model_step(
+                self.params, self.cfg, jnp.asarray(self.row_token),
+                jnp.asarray(self.row_pos), self.pool)
         return logits
 
 
@@ -1723,6 +1738,40 @@ class PagedScheduler(_SchedulerBase):
         self._page_peak = max(self._page_peak, self.alloc.used_count)
 
     def _decode_tick(self):
+        # page growth, COW certification and the step's operands, then
+        # the one fused step dispatch
+        with self.tick_time.span("pages"):
+            fused, wp = self._step_operands()
+        with self.tick_time.span("step"):
+            if fused:
+                self.counters["fused_chunks"] += len(fused)
+                chunks, auxs_in = [], []
+                for rid, pf, c, args in fused:
+                    chunks.append(args)
+                    auxs_in.append(pf.aux)
+                logits, clogits, self.pool, auxs = \
+                    engine._fused_decode_chunks(
+                        self.params, self.cfg, jnp.asarray(self.row_token),
+                        jnp.asarray(self.row_pos), self.pool, self._bt_dev,
+                        jnp.asarray(wp), tuple(chunks), tuple(auxs_in))
+                out = {}
+                for (rid, pf, c, _), cl, aux in zip(fused, clogits, auxs):
+                    pf.filled += c
+                    pf.aux = aux
+                    out[rid] = cl
+                self._fused_chunk_out = out
+                return logits
+            logits, self.pool = _paged_step(
+                self.params, self.cfg, jnp.asarray(self.row_token),
+                jnp.asarray(self.row_pos), self.pool, self._bt_dev,
+                jnp.asarray(wp))
+        return logits
+
+    def _step_operands(self):
+        """Grow every fused chunk's pages and every active row's, certify
+        the rows' write pages and upload the block table; returns the
+        surviving fused chunks ``(rid, prefill, length, operands)`` and
+        the write pages."""
         # step-fault injection point: BEFORE chunk growth and
         # _ensure_pages, so a fault aborts the tick with the allocator
         # and pool untouched (retry is then trivially sound — the
@@ -1756,28 +1805,8 @@ class PagedScheduler(_SchedulerBase):
         self._account_pages_tick()
         if self._bt_dev is None:
             self._bt_dev = jnp.asarray(self.alloc.block)
-        if fused:
-            self.counters["fused_chunks"] += len(fused)
-            chunks, auxs_in = [], []
-            for rid, pf, c in fused:
-                chunks.append(self._chunk_args(pf, c))
-                auxs_in.append(pf.aux)
-            logits, clogits, self.pool, auxs = engine._fused_decode_chunks(
-                self.params, self.cfg, jnp.asarray(self.row_token),
-                jnp.asarray(self.row_pos), self.pool, self._bt_dev,
-                jnp.asarray(wp), tuple(chunks), tuple(auxs_in))
-            out = {}
-            for (rid, pf, c), cl, aux in zip(fused, clogits, auxs):
-                pf.filled += c
-                pf.aux = aux
-                out[rid] = cl
-            self._fused_chunk_out = out
-            return logits
-        logits, self.pool = _paged_step(
-            self.params, self.cfg, jnp.asarray(self.row_token),
-            jnp.asarray(self.row_pos), self.pool, self._bt_dev,
-            jnp.asarray(wp))
-        return logits
+        return [(rid, pf, c, self._chunk_args(pf, c))
+                for rid, pf, c in fused], wp
 
     def _post_tick_prefill(self) -> None:
         rids, self._fused_rids = self._fused_rids, []

@@ -186,7 +186,9 @@ class PooledKappaController:
             jnp.asarray(gather_idx), jnp.asarray(done_prev),
             jnp.asarray(self.pending_reset), jnp.asarray(self.slot_active),
             jnp.asarray(self.row_n), self.log_q, jnp.int32(eos_id))
-        self.pending_reset[:] = False
+        # a fresh array, not a clear in place: the host-to-device copy of
+        # the flags may still be in flight after the dispatch returns
+        self.pending_reset = np.zeros_like(self.pending_reset)
         self.dispatches += 1
         return out
 
