@@ -125,7 +125,7 @@ INTERLEAVE_REPS = 3             # best-of-R (CPU wall-clock noise; rep 1
                                 # also absorbs jit compiles)
 
 BREAKDOWN_KEYS = ("admit", "prefill", "pages", "step", "keys",
-                  "keys_wait", "sample", "control", "sync", "host")
+                  "sample", "control", "sync", "host")
 
 
 def _tick_breakdown_us(tp):

@@ -8,8 +8,8 @@ Both rules mechanize serving contracts that used to live only in prose:
   (PR 7/PR 8). A stray wall-clock read or ambient-RNG draw in the
   serving/core layers silently breaks that equivalence.
 * DESIGN.md §4 — the fused tick performs **at most one blocking
-  controller-carrying transfer per tick** (PR 3), with the sampler-key
-  fetch as the only other sanctioned transfer. Any new ``.item()`` /
+  controller-carrying transfer per tick** (PR 3), and no other: the
+  sampling keys are derived on the device. Any new ``.item()`` /
   ``device_get`` / host-coercion in a tick-path module is either a
   regression or a new sanctioned site that must be added to the
   explicit allowlist below (and to the dynamic counter twin in
@@ -146,9 +146,9 @@ class ReplayDeterminism(Rule):
 # these sites increment stay within the ≤1-controller-sync-per-tick
 # budget, so this list and runtime truth cannot drift apart silently.
 ALLOWED_SYNC_SITES = {
-    # the fused tick's two sanctioned transfers: the per-row sampler-key
-    # fetch and THE blocking transfer carrying tokens + picked log-probs
-    # + pooled controller outputs + the finite mask (DESIGN.md §4)
+    # the fused tick's one sanctioned transfer: THE blocking transfer
+    # carrying tokens + picked log-probs + pooled controller outputs +
+    # the finite mask (DESIGN.md §4; sampling keys stay on the device)
     ("scheduler.py", "tick"),
     # engine-loop twin of the tick sync: the single-request path reads
     # its own sampled tokens back each step by design
@@ -169,8 +169,8 @@ class SyncDiscipline(Rule):
     rationale = (
         "PR 3 collapsed the per-request controller host reads into ONE "
         "pooled dispatch whose outputs ride the tick's single blocking "
-        "device_get; the tick's only other transfer is the sampler-key "
-        "fetch. Every `.item()`, `jax.device_get`, `block_until_ready`, "
+        "device_get, and the sampling keys never leave the device. "
+        "Every `.item()`, `jax.device_get`, `block_until_ready`, "
         "`np.asarray`, or float()/int() coercion of a jax value in a "
         "tick-path module is a potential hidden round-trip that "
         "serializes host and device again. New sites must be allowlisted "

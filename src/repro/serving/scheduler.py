@@ -42,7 +42,10 @@ Shared driver behaviour per tick:
   * one fused decode step over the whole pool with per-row positions;
   * ONE fused sampler dispatch for every active request's rows
     (per-row RNG keys — :func:`repro.serving.sampler.sample_rows`)
-    instead of a per-request ``sample_step`` call;
+    instead of a per-request ``sample_step`` call. Each active
+    request's RNG stream lives in a device table while it decodes, and
+    the sampler program derives every row's key from it, so the tick
+    has no per-request key work on the host;
   * ONE pooled KAPPA-controller dispatch for every active kappa request
     (:class:`repro.serving.strategies.PooledKappaController`): the
     stacked controller state consumes the pool logits and just-sampled
@@ -130,6 +133,8 @@ _copy_pages = jax.jit(cache_lib.copy_pages, static_argnums=(0,),
                       donate_argnums=(1,))
 _install_aux = jax.jit(cache_lib.install_rows_aux, static_argnums=(0,),
                        donate_argnums=(1,))
+_put_stream = jax.jit(lambda table, s, key: table.at[s].set(key),
+                      donate_argnums=(0,))
 
 
 @dataclasses.dataclass
@@ -242,6 +247,21 @@ class _SchedulerBase:
         self._fused_chunk_out = None         # fused decode dispatch
         self.active: Dict[int, tuple] = {}   # rid -> (RequestState, slots)
         self._slots_dev: Dict[int, object] = {}  # rid -> device slot idx
+        # fused sampling: each active request's RNG stream lives in one
+        # slot of a device table (raw threefry key data) while it
+        # decodes; the sampler program advances it and derives the row
+        # keys. The per-row operands change only when rows do.
+        self._streams = None
+        if fused_sampling:
+            sampler.check_partitionable()
+            self._streams = jnp.zeros((rows, 2), jnp.uint32)
+        self._free_streams: List[int] = list(range(rows))
+        self._stream_of: Dict[int, int] = {}  # rid -> stream slot
+        self._stream_adv = np.zeros((rows,), bool)
+        self._row_stream = np.zeros((rows,), np.int32)
+        self._row_branch = np.zeros((rows,), np.int32)
+        self._row_greedy = np.ones((rows,), bool)  # free rows: argmax
+        self._n_want_lp = 0                  # active requests reading lp
         self._items: Dict[int, _Queued] = {}  # rid -> original submission
         self._admit_seq: Dict[int, int] = {}  # rid -> admission order
         self._admit_counter = 0
@@ -538,8 +558,7 @@ class _SchedulerBase:
             self._release(pf.slots)
             res = self._empty_result(item, status)
         else:
-            rs, slots = self.active.pop(rid)
-            self._slots_dev.pop(rid, None)
+            rs, slots = self._deactivate(rid)
             res = rs.result()            # BEFORE release_pool (mirrors)
             res.status = status
             res.n_retries = item.n_retries
@@ -561,8 +580,7 @@ class _SchedulerBase:
                                        pf.filled)
             self._release(pf.slots)
         else:
-            rs, slots = self.active.pop(rid)
-            self._slots_dev.pop(rid, None)
+            rs, slots = self._deactivate(rid)
             item = self._items[rid]
             self._publish_prompt_pages(item.prompt, slots[0],
                                        len(item.prompt))
@@ -728,10 +746,47 @@ class _SchedulerBase:
             self._items.pop(item.rid, None)
             self._admit_seq.pop(item.rid, None)
         else:
-            self.active[item.rid] = (rs, slots)
-            self._slots_dev[item.rid] = jnp.asarray(slots)
+            self._activate(item.rid, rs, slots)
             self.row_token[slots] = rs.cur
             self.row_pos[slots] = rs.pos
+
+    def _activate(self, rid: int, rs: strategies.RequestState,
+                  slots: List[int]) -> None:
+        """Make ``rid`` active on ``slots``. Fused sampling moves its RNG
+        stream (advanced past the first tokens on the host) into a
+        device stream slot; from here on ``rs.rng`` is stale."""
+        if self._streams is not None:
+            s = self._free_streams.pop()
+            self._stream_of[rid] = s
+            self._streams = _put_stream(self._streams, s,
+                                        strategies.raw_key(rs.rng))
+            self._stream_adv[s] = True
+            self._n_want_lp += rs.strategy.wants_picked_lp
+        self._set_rows(rid, rs, slots)
+
+    def _set_rows(self, rid: int, rs: strategies.RequestState,
+                  slots: List[int]) -> None:
+        """Point ``slots`` (the request's live rows, in branch order) at
+        its stream, as admission and compaction leave them."""
+        self.active[rid] = (rs, slots)
+        self._slots_dev[rid] = jnp.asarray(slots)
+        if self._streams is not None:
+            self._row_stream[slots] = self._stream_of[rid]
+            self._row_branch[slots] = np.arange(len(slots))
+            self._row_greedy[slots] = rs.strategy.greedy
+
+    def _deactivate(self, rid: int):
+        """Take ``rid`` out of the active set and free its stream slot
+        (nothing is copied back: a replay restarts from the submission
+        RNG). Returns its ``(RequestState, slots)``."""
+        rs, slots = self.active.pop(rid)
+        self._slots_dev.pop(rid, None)
+        s = self._stream_of.pop(rid, None)
+        if s is not None:
+            self._stream_adv[s] = False
+            self._free_streams.append(s)
+            self._n_want_lp -= rs.strategy.wants_picked_lp
+        return rs, slots
 
     def _fuse_candidates(self) -> List[int]:
         """rids of the PREFILLING requests whose next chunks should ride
@@ -785,6 +840,7 @@ class _SchedulerBase:
         self._release_storage(slots)
         self.row_token[slots] = 0
         self.row_pos[slots] = 0
+        self._row_greedy[slots] = True
         self.free.extend(slots)
         self.free.sort()
 
@@ -907,31 +963,24 @@ class _SchedulerBase:
 
             toks = picked = finite = None
             if self.fused_sampling:
-                # one fused per-row-keyed sampling dispatch for the whole
-                # pool; free rows ride along as masked argmax (ignored)
-                keys = np.zeros((self.rows, 2), np.uint32)
-                gmask = np.ones((self.rows,), bool)
-                want_lp = False
-                key_devs = {}
+                # one fused sampling dispatch for the whole pool, which
+                # advances every active stream and derives the row keys
+                # on the device; free rows ride along as masked argmax
+                # (ignored)
                 with tt.span("keys"):
-                    for rid, (rs, slots) in self.active.items():
-                        key_devs[rid] = rs.step_keys()   # device splits
-                        gmask[slots] = rs.strategy.greedy
-                        want_lp |= rs.strategy.wants_picked_lp
-                with tt.span("keys_wait"):
-                    key_np = jax.device_get(key_devs)  # blocking transfer
-                self.counters["host_syncs"] += 1
+                    streams = sampler.RowStreams(
+                        self._streams, jnp.asarray(self._row_stream),
+                        jnp.asarray(self._row_branch),
+                        jnp.asarray(self._stream_adv))
+                    gmask = jnp.asarray(self._row_greedy)
                 with tt.span("sample"):
-                    for rid, (rs, slots) in self.active.items():
-                        keys[slots] = key_np[rid]
                     # picked-token log-probs fused into the sampling
                     # dispatch so BoN-style strategies do zero device
                     # work per request
-                    out_dev = sampler.sample_rows(
-                        jnp.asarray(keys), logits, jnp.asarray(gmask),
-                        self.kcfg, want_picked_lp=want_lp)
+                    toks_dev, lp_dev, self._streams = sampler.sample_rows(
+                        streams, logits, gmask, self.kcfg,
+                        want_picked_lp=self._n_want_lp > 0)
                 self.counters["sampler_dispatches"] += 1
-                toks_dev = out_dev[0] if want_lp else out_dev
 
                 # the pooled controller consumes the pool logits and the
                 # just-sampled tokens device-to-device — no host
@@ -944,13 +993,12 @@ class _SchedulerBase:
                 # traj/cutoff of every kappa request), independent of
                 # active-request count
                 with tt.span("sync"):
-                    out, ctrl_host, finite = jax.device_get(
-                        (out_dev, ctrl_dev, finite_dev))
+                    toks, picked, ctrl_host, finite = jax.device_get(
+                        (toks_dev, lp_dev, ctrl_dev, finite_dev))
                     self.counters["host_syncs"] += 1
                     if ctrl_host is not None:
                         self.counters["controller_syncs"] += 1
                         self._kappa_pool.publish(ctrl_host)
-                    toks, picked = out if want_lp else (out, None)
             elif finite_dev is not None:
                 finite = jax.device_get(finite_dev)
 
@@ -994,8 +1042,7 @@ class _SchedulerBase:
                 kept = [slots[i] for i in dec.keep]
                 self._release(sorted(set(slots) - set(kept)))
                 slots = kept
-                self.active[rid] = (rs, slots)
-                self._slots_dev[rid] = jnp.asarray(slots)
+                self._set_rows(rid, rs, slots)
             self.row_token[slots] = rs.cur
             self.row_pos[slots] = rs.pos
             if rs.finished:
@@ -1139,10 +1186,10 @@ class _SchedulerBase:
         }
         # host seconds per tick phase (serving/spans.py): "step" and
         # "sample"/"control" time the enqueue of the device programs,
-        # "keys_wait" and "sync" the two blocking transfers, and "host"
-        # the per-request advance loop (which absorbs UNPOOLED
-        # controller dispatch + sync — the regression the breakdown
-        # exists to make visible)
+        # "keys" the hand-off of the sampler's per-row operands, "sync"
+        # the one blocking transfer, and "host" the per-request advance
+        # loop (which absorbs UNPOOLED controller dispatch + sync — the
+        # regression the breakdown exists to make visible)
         for k, v in self.tick_time.items():
             out[f"time_{k}_s"] = v
         out.update(self.counters)

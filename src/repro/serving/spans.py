@@ -32,8 +32,7 @@ PREFIX = "serve."
 # the scheduler's phases, in the order a tick opens them ("frontend" is
 # the front end's yield after a tick)
 PHASES = ("tick", "admit", "prefill", "pages", "step", "keys",
-          "keys_wait", "sample", "control", "sync", "host", "emit",
-          "frontend")
+          "sample", "control", "sync", "host", "emit", "frontend")
 
 
 def _now() -> float:

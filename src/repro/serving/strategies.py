@@ -579,6 +579,19 @@ def make_strategy(name: str, **kw) -> DecodeStrategy:
 
 # ----------------------------------------------------------- request state
 
+def raw_key(keys):
+    """Raw ``(..., 2)`` uint32 key data of ``keys``, typed or raw; keys
+    of a wider impl (e.g. rbg's 4 words) are refused rather than
+    misread."""
+    if jnp.issubdtype(keys.dtype, jax.dtypes.prng_key):
+        keys = jax.random.key_data(keys)
+    if keys.shape[-1] != 2:
+        raise ValueError(
+            f"request RNG uses a {keys.shape[-1]}-word key impl; the "
+            "serving stack supports 2-word (threefry) keys only")
+    return keys
+
+
 class RequestState:
     """Method-agnostic host state of one in-flight request.
 
@@ -586,7 +599,12 @@ class RequestState:
     logical/compute/byte accounting. The driver (engine loop or
     scheduler) owns the device cache; it applies ``StepDecision.keep``
     to its own row storage (gather for a dedicated cache, slot freeing
-    for the shared pool)."""
+    for the shared pool).
+
+    A scheduler with fused sampling holds the stream itself, in a device
+    table, from activation (after :meth:`first_tokens`) until the
+    request leaves the pool; ``rng`` is stale over that time and nothing
+    reads it (a replay starts a new state from the submission RNG)."""
 
     def __init__(self, strategy: DecodeStrategy, params, cfg: ModelConfig,
                  kcfg: KappaConfig, prompt_len: int, rng, *, eos_id: int,
@@ -629,25 +647,18 @@ class RequestState:
 
     def step_keys(self):
         """Advance this request's RNG stream and derive one sampling key
-        per live row. The scheduler gathers these across requests into a
-        single fused :func:`repro.serving.sampler.sample_rows` dispatch;
-        the engine loop uses them via :meth:`sample_and_advance`. Both
-        consume the stream identically, so tokens match across modes.
+        per live row: the first tokens and the engine loop
+        (:meth:`sample_and_advance`) use them. The fused scheduler tick
+        steps the stream the same way on the device
+        (:class:`repro.serving.sampler.RowStreams`), so tokens match
+        across modes.
 
         Returned keys are always raw (n, 2) uint32 key data — new-style
         *threefry* typed keys (``jax.random.key``'s default impl) are
-        unwrapped so the scheduler's pooled key buffer works for either
-        flavor the caller submitted. Wider key impls (e.g. rbg's 4-word
-        data) are rejected up front rather than silently misread."""
+        unwrapped (:func:`raw_key`), so either flavor the caller
+        submitted works."""
         self.rng, kk = jax.random.split(self.rng)
-        keys = jax.random.split(kk, len(self.branch_ids))
-        if jnp.issubdtype(keys.dtype, jax.dtypes.prng_key):
-            keys = jax.random.key_data(keys)
-        if keys.shape[-1] != 2:
-            raise ValueError(
-                f"request RNG uses a {keys.shape[-1]}-word key impl; the "
-                "serving stack supports 2-word (threefry) keys only")
-        return keys
+        return raw_key(jax.random.split(kk, len(self.branch_ids)))
 
     def _greedy_mask(self, n: int):
         return jnp.full((n,), self.strategy.greedy)

@@ -36,8 +36,8 @@ def fake_clock():
 # static allowlist (rules/determinism.py ALLOWED_SYNC_SITES) names the
 # sanctioned blocking-transfer call sites; this guard asserts the
 # runtime counters those sites increment stay within the DESIGN.md §4
-# budget — ≤1 pooled-controller sync per tick riding ≤2 blocking
-# transfers per tick — on EVERY scheduler any scheduler-level test
+# budget — ≤1 pooled-controller sync per tick riding ≤1 blocking
+# transfer per tick — on EVERY scheduler any scheduler-level test
 # constructs. The two can't drift apart silently: a new sync site
 # trips the lint, a new per-tick transfer trips this.
 _SYNC_GUARDED_FILES = ("test_scheduler.py", "test_paged.py")
@@ -70,11 +70,11 @@ def _sync_budget_guard(request, monkeypatch):
             f"{c['controller_syncs']} syncs / "
             f"{c['controller_dispatches']} dispatches over "
             f"{sched.ticks} ticks (≤1 per tick, DESIGN.md §4)")
-        # the fused tick's two sanctioned transfers: sampler keys + THE
-        # tokens/controller/finite transfer
-        assert c["host_syncs"] <= 2 * sched.ticks, (
+        # the fused tick's one sanctioned transfer: THE tokens/
+        # controller/finite transfer (sampling keys stay on the device)
+        assert c["host_syncs"] <= sched.ticks, (
             f"host-sync budget exceeded: {c['host_syncs']} blocking "
-            f"transfers over {sched.ticks} ticks (≤2 per tick)")
+            f"transfers over {sched.ticks} ticks (≤1 per tick)")
 
 try:
     import pytest_timeout  # noqa: F401

@@ -336,8 +336,9 @@ def test_paged_mixed_pool_batched_controller_contract(setup):
     assert sched.counters["controller_dispatches"] <= sched.ticks
     assert sched.counters["controller_syncs"] == \
         sched.counters["controller_dispatches"]
-    # ≤ 2 blocking transfers per tick total (RNG keys + tokens/controller)
-    assert sched.counters["host_syncs"] <= 2 * sched.ticks
+    # ≤ 1 blocking transfer per tick total (tokens/controller; the RNG
+    # keys are derived on the device)
+    assert sched.counters["host_syncs"] <= sched.ticks
     # pool fully drained
     assert sorted(sched.free) == list(range(12))
     assert sorted(sched._kappa_pool.free) == list(range(12))

@@ -4,7 +4,7 @@ and adds its host seconds to ``tick_time`` (a parent's at least its
 children's); the front end's yield between ticks and full collections
 are spans too; a real profiler trace on the CPU holds the spans on the
 host plane; and under the profiler the scheduler serves the same
-tokens with the same two blocking transfers per decode tick."""
+tokens with the same one blocking transfer per decode tick."""
 import asyncio
 import gc
 import glob
@@ -24,11 +24,10 @@ from repro.serving.scheduler import PagedScheduler
 # a decode tick, in the order and nesting its spans open
 DECODE_TICK = [(0, "serve.tick"), (1, "serve.admit"), (1, "serve.prefill"),
                (1, "serve.pages"), (1, "serve.step"), (1, "serve.keys"),
-               (1, "serve.keys_wait"), (1, "serve.sample"),
-               (1, "serve.control"), (1, "serve.sync"), (1, "serve.host"),
-               (2, "serve.emit")]
+               (1, "serve.sample"), (1, "serve.control"), (1, "serve.sync"),
+               (1, "serve.host"), (2, "serve.emit")]
 CHILDREN = {"tick": ("admit", "prefill", "pages", "step", "keys",
-                     "keys_wait", "sample", "control", "sync", "host"),
+                     "sample", "control", "sync", "host"),
             "host": ("emit",)}
 
 
@@ -174,7 +173,7 @@ def test_full_collections_are_spans(notes):
 
 def test_profiled_serving_is_token_equal_and_traced(setup, tmp_path):
     """Under the JAX profiler (CPU) the scheduler serves the same tokens
-    with the same two blocking transfers per decode tick, and the trace's
+    with the same one blocking transfer per decode tick, and the trace's
     host plane holds every tick phase, nested inside its ``serve.tick``
     (which carries the tick's step number)."""
     plain, want = _serve(setup)
@@ -183,7 +182,7 @@ def test_profiled_serving_is_token_equal_and_traced(setup, tmp_path):
     assert got == want
     for s in (plain, traced):
         assert s.counters["host_syncs"] \
-            == 2 * s.counters["sampler_dispatches"]
+            == s.counters["sampler_dispatches"]
     assert traced.counters["host_syncs"] == plain.counters["host_syncs"]
 
     path, = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
