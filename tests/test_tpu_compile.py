@@ -1,7 +1,9 @@
 """Compile the served path's paged attention kernel for a described TPU
-v5e, at the published widths of deepseek-r1-distill-qwen-1.5b: 12 query
-heads over 2 KV heads, head dim 128, 64-token pages, bf16 and int8
-pages, one decode token and one prefill chunk.
+v5e, at the published widths of the benchmark's models:
+deepseek-r1-distill-qwen-1.5b (12 query heads over 2 KV heads) and
+qwen2.5-7b-instruct (28 over 4, an odd group of 7), head dim 128,
+64-token pages, bf16 and int8 pages, one decode token and one prefill
+chunk.
 
 Interpret mode, which every other kernel test runs, checks none of the
 TPU lowering's rules (block tiling, VMEM size). The TPU compiler is
@@ -25,7 +27,11 @@ from repro.kernels.decode_attn.kernel import (paged_decode_attn_pallas,
                                               paged_prefill_attn_pallas)
 
 PAGE_SIZE = 64
-PREFILL_CHUNK = 16      # the chunk size chip_smoke.py serves with
+# model -> (H, KV, hd) and the prefill chunk compiled: the one
+# chip_smoke.py serves r1d with, and the one the benchmark serves
+# qwen2.5-7b with (3,584 query rows of a KV head in VMEM)
+MODELS = {"deepseek-r1-distill-qwen-1.5b": ((12, 2, 128), 16),
+          "qwen2.5-7b-instruct": ((28, 4, 128), 512)}
 ROWS = 10               # decode pool rows: two N=5 fan-outs
 MAX_PAGES = 32          # 2048-token block tables
 NUM_PAGES = 512
@@ -55,13 +61,16 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("chunk", [1, PREFILL_CHUNK],
+@pytest.mark.parametrize("prefill", [False, True],
                          ids=["decode", "prefill_chunk"])
 @pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
-def test_paged_kernel_compiles_for_v5e(one_chip, kv_dtype, chunk):
-    cfg = get_config("deepseek-r1-distill-qwen-1.5b")
+@pytest.mark.parametrize("arch", list(MODELS))
+def test_paged_kernel_compiles_for_v5e(one_chip, arch, kv_dtype, prefill):
+    cfg = get_config(arch)
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
-    assert (H, KV, hd) == (12, 2, 128)
+    widths, prefill_chunk = MODELS[arch]
+    assert (H, KV, hd) == widths
+    chunk = prefill_chunk if prefill else 1
     B = ROWS if chunk == 1 else 1       # prefill chunks run batch-1
 
     def shape(shp, dtype):
