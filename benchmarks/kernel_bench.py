@@ -6,17 +6,23 @@ fusion arithmetic: fused_score reads the logits row once vs 4×.
 Also *executes* every Pallas kernel wrapper end to end (fused_score,
 decode_attn contiguous + paged, rwkv6_scan) at small shapes — the CI
 smoke step runs this module so a broken pallas_call surfaces on push,
-not only in the unit-test sweeps."""
+not only in the unit-test sweeps.
+
+On a TPU it times the paged decode kernel alone at the decode call of
+the benchmark cell ``r1d-1.5b.kappa-batch`` in place of the wrapper
+smoke, whose contiguous decode_attn call does not lower there."""
 from __future__ import annotations
 
 import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.signals import compute_signals, log_softmax, reference_log_q
 from repro.kernels.decode_attn.ops import (decode_attn, paged_decode_attn,
                                            paged_prefill_attn)
+from repro.kernels import on_tpu
 from repro.kernels.fused_score.ops import fused_score
 from repro.kernels.rwkv6_scan.ops import rwkv6_scan
 
@@ -52,8 +58,53 @@ def run(cfg=None, params=None):
         bytes_once = B * V * 4
         rows.append({"name": f"signals_B{B}_V{V}", "us_fused": us_fused,
                      "us_separate": us_sep, "row_bytes": bytes_once})
-    rows.extend(_wrapper_smoke())
+    if on_tpu():
+        # the TPU lowering refuses the smoke's contiguous decode_attn
+        # blocks, (1, S-tile, 1, hd) of a 2-KV-head cache
+        rows.append(_paged_decode_cell())
+    else:
+        rows.extend(_wrapper_smoke())
     return rows
+
+
+def _cell_positions(rng, rows):
+    """Row positions as the closed-loop KAPPA batch cell holds them:
+    prompts lognormal (median 160, sigma 0.6) in 48-512, answers
+    lognormal (median 1,200, sigma 0.7) in 256-4,096; a row is busy in
+    proportion to its answer's length and sits at a uniform point of
+    it."""
+    prompt = np.clip(rng.lognormal(np.log(160), 0.6, 4096), 48, 512)
+    answer = np.clip(rng.lognormal(np.log(1200), 0.7, 4096), 256, 4096)
+    pick = rng.choice(4096, size=rows, p=answer / answer.sum())
+    return (prompt[pick] + rng.uniform(size=rows) * answer[pick]).astype(
+        np.int32)
+
+
+def _paged_decode_cell(iters=50):
+    """The paged decode kernel alone at ``r1d-1.5b.kappa-batch``'s decode
+    call: 104 rows, 12 query heads over 2 KV heads, hd 128, bf16 64-token
+    pages, 80-page block tables (max_seq 5,120), rows at the cell's
+    lengths, their pages drawn from a 2,304-page pool (rows may share
+    pages, as a fan-out shares its prompt's)."""
+    B, H, KV, hd, ps, MP, P = 104, 12, 2, 128, 64, 80, 2305
+    rng = np.random.RandomState(0)
+    pos = _cell_positions(rng, B)
+    bt = np.full((B, MP), P - 1, np.int32)         # tails: the trash page
+    for b in range(B):
+        bt[b, :pos[b] // ps + 1] = rng.choice(P - 1, pos[b] // ps + 1,
+                                              replace=False)
+    ks = jax.random.split(jax.random.PRNGKey(1), 3)
+    q = jax.random.normal(ks[0], (B, H, hd), jnp.bfloat16)
+    kp = jax.random.normal(ks[1], (P, KV, ps, hd), jnp.bfloat16)
+    vp = jax.random.normal(ks[2], (P, KV, ps, hd), jnp.bfloat16)
+    step = jax.jit(paged_decode_attn)
+    us = _time(lambda *a: (step(*a),), q, kp, vp, jnp.asarray(bt),
+               jnp.asarray(pos), iters=iters)
+    # the K and V bytes the rows need: positions 0..pos of each KV head
+    need = int((pos.astype(np.int64) + 1).sum()) * KV * hd * 2 * 2
+    return {"name": "paged_decode_r1d_cell", "us_fused": us,
+            "kv_bytes": need, "live_pages": int((pos // ps + 1).sum()),
+            "table_pages": B * MP}
 
 
 def _wrapper_smoke():
@@ -171,6 +222,11 @@ def emit_csv(rows):
             out.append(f"kernel_bench/{r['name']},{r['us_fused']:.1f},"
                        f"separate_us={r['us_separate']:.1f};"
                        f"row_bytes={r['row_bytes']}")
+        elif "kv_bytes" in r:
+            out.append(f"kernel_bench/{r['name']},{r['us_fused']:.1f},"
+                       f"kv_bytes={r['kv_bytes']};"
+                       f"live_pages={r['live_pages']};"
+                       f"table_pages={r['table_pages']}")
         else:
             out.append(f"kernel_bench/{r['name']},{r['us_fused']:.1f},"
                        f"wrapper_smoke=1")

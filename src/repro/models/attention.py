@@ -692,9 +692,9 @@ def attn_decode_paged(p, x, pos, cache, block_tables, write_pages=None, *,
         vq[:, 0].astype(cache["v"].dtype))
 
     if use_paged_kernel():
-        # paged flash-decode kernel: the S-tile index map dereferences the
-        # block table, so only owned (and trash-aliased) pages stream
-        # through VMEM — no (B, MP*ps, ...) gather materialized in HBM.
+        # paged flash-decode kernel: it copies each row's live pages
+        # (up to pos) through the block table into VMEM — no
+        # (B, MP*ps, ...) gather materialized in HBM.
         # Int8 pools pass their scale pages for in-kernel dequant (the
         # quantized case used to silently drop to the oracle below).
         from repro.kernels.decode_attn.ops import paged_decode_attn

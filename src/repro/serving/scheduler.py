@@ -1415,6 +1415,9 @@ class PagedScheduler(_SchedulerBase):
             "prefix_hits": 0, "prefix_misses": 0,
             "prefix_tokens_saved": 0, "prefix_evictions": 0,
             "fused_chunks": 0,
+            # pages the decode kernel walks (each row's up to its
+            # position) against its block-table slots (rows x max_pages)
+            "kv_pages_live": 0, "kv_pages_table": 0,
         })
         self._page_ticks = 0                 # Σ pages in use over ticks
         self._page_peak = 0                  # max pages in use at any tick
@@ -1850,6 +1853,9 @@ class PagedScheduler(_SchedulerBase):
         if occ.size:
             wp[occ] = self.alloc.write_page(occ, self.row_pos[occ])
         self._account_pages_tick()
+        self.counters["kv_pages_live"] += int(
+            (self.row_pos // self.page_size + 1).sum())
+        self.counters["kv_pages_table"] += self.rows * self.max_pages
         if self._bt_dev is None:
             self._bt_dev = jnp.asarray(self.alloc.block)
         return [(rid, pf, c, self._chunk_args(pf, c))

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels.decode_attn.kernel import _pages_per_tile
 from repro.kernels.decode_attn.ops import (decode_attn, paged_decode_attn,
                                            paged_prefill_attn)
 from repro.kernels.decode_attn.ref import (decode_attn_ref,
@@ -78,24 +79,46 @@ def test_decode_attn_sweep(B, H, KV, hd, S, pos, window, ring, dtype):
                                rtol=tol, atol=tol)
 
 
+# ``B`` in the sweeps below is a number of rows at random positions, or
+# EDGES: four rows whose last positions sit at 0, at the last slot of
+# the kernel's first page tile, at the first slot of the next tile, and
+# at the last slot of the block table
+EDGES = "edges"
+
+
+def _last_positions(rng, B, MP, ps, tile_pages):
+    """(rows, each row's last position) for a sweep case."""
+    if B == EDGES:
+        edges = [0, tile_pages * ps - 1, tile_pages * ps, MP * ps - 1]
+        return 4, np.minimum(edges, MP * ps - 1).astype(np.int32)
+    return B, rng.randint(0, MP * ps, size=B).astype(np.int32)
+
+
 @pytest.mark.parametrize("B,H,KV,hd,ps,MP,P", [
     (2, 8, 2, 64, 16, 4, 12),     # GQA
     (1, 4, 4, 32, 8, 8, 10),      # MHA, many small pages
     (3, 6, 3, 128, 32, 2, 8),     # odd head count, 2 logical pages
     (2, 4, 1, 64, 64, 3, 7),      # MQA, page = S-tile
+    # tiles of several 64-token pages; the table is no multiple of them
+    (EDGES, 12, 2, 128, 64, 20, 81),   # r1d's group of 6
+    (EDGES, 28, 4, 128, 64, 17, 69),   # qwen2.5-7b's group of 7
+    (3, 8, 2, 128, 64, 11, 34),
+    (EDGES, 8, 2, 64, 64, 20, 81),     # a tile of blocks, hd < 128 lanes
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_decode_attn_sweep(B, H, KV, hd, ps, MP, P, dtype):
     """Paged kernel vs the pure-jnp paged oracle, scrambled block tables
     and per-row positions (trash-aliased tails included)."""
-    rng = np.random.RandomState(B * H + ps)
+    rng = np.random.RandomState((4 if B == EDGES else B) * H + ps)
+    tile = _pages_per_tile(H // KV, ps, hd, jnp.dtype(dtype).itemsize,
+                           False, MP)
+    # each row: its position, owned pages drawn without replacement,
+    # unowned entries alias the last physical page (trash convention)
+    B, pos = _last_positions(rng, B, MP, ps, tile)
     ks = jax.random.split(jax.random.PRNGKey(hash((B, H, ps, MP)) % 2**31), 3)
     q = jax.random.normal(ks[0], (B, H, hd)).astype(dtype)
     kp = jax.random.normal(ks[1], (P, KV, ps, hd)).astype(dtype)
     vp = jax.random.normal(ks[2], (P, KV, ps, hd)).astype(dtype)
-    # each row: random position, owned pages drawn without replacement,
-    # unowned entries alias the last physical page (trash convention)
-    pos = rng.randint(0, MP * ps, size=B).astype(np.int32)
     bt = np.full((B, MP), P - 1, np.int32)
     for b in range(B):
         owned = pos[b] // ps + 1
@@ -131,20 +154,24 @@ def _scrambled_tables(rng, B, MP, P, ps, pos):
     (2, 8, 2, 64, 16, 4, 12),     # GQA
     (1, 4, 4, 32, 8, 8, 10),      # MHA, many small pages
     (2, 4, 1, 64, 64, 3, 7),      # MQA, page = S-tile
+    # tiles of several 64-token pages; the table is no multiple of them
+    (EDGES, 12, 2, 128, 64, 45, 181),  # r1d's group of 6
+    (EDGES, 28, 4, 128, 64, 23, 93),   # qwen2.5-7b's group of 7
 ])
 def test_paged_decode_attn_int8_sweep(B, H, KV, hd, ps, MP, P):
     """Int8 paged kernel vs the int8-aware oracle: both dequantize the
     same int8 pages with the same scales, so the comparison is tight.
     A loose check against the unquantized oracle bounds the actual
     quantization error."""
-    rng = np.random.RandomState(B * H + ps + 1)
+    rng = np.random.RandomState((4 if B == EDGES else B) * H + ps + 1)
+    tile = _pages_per_tile(H // KV, ps, hd, 1, True, MP)
+    B, pos = _last_positions(rng, B, MP, ps, tile)
     ks = jax.random.split(jax.random.PRNGKey(hash((B, H, ps, MP, 8)) % 2**31), 3)
     q = jax.random.normal(ks[0], (B, H, hd))
     kp = jax.random.normal(ks[1], (P, KV, ps, hd))
     vp = jax.random.normal(ks[2], (P, KV, ps, hd))
     kq, ksc = _quantize_pages(kp)
     vq, vsc = _quantize_pages(vp)
-    pos = rng.randint(0, MP * ps, size=B).astype(np.int32)
     bt = _scrambled_tables(rng, B, MP, P, ps, pos)
     out = paged_decode_attn(q, kq, vq, jnp.asarray(bt), jnp.asarray(pos),
                             k_scales=ksc, v_scales=vsc)
@@ -162,20 +189,30 @@ def test_paged_decode_attn_int8_sweep(B, H, KV, hd, ps, MP, P):
     (1, 7, 4, 4, 32, 8, 8, 10),   # MHA, chunk not a page multiple
     (2, 1, 4, 1, 64, 16, 3, 7),   # MQA, single-token chunk (= decode)
     (1, 16, 6, 3, 128, 32, 2, 8), # odd head count, chunk = half a page
+    # tiles of several 64-token pages, by the chunk's last token
+    (EDGES, 3, 8, 2, 128, 64, 20, 81),
+    (EDGES, 2, 28, 4, 128, 64, 17, 69),  # qwen2.5-7b's group of 7
 ])
 @pytest.mark.parametrize("quant", [False, True])
 def test_paged_prefill_attn_sweep(B, C, H, KV, hd, ps, MP, P, quant):
     """Paged chunk-prefill kernel vs the pure-jnp causal oracle: random
     chunk offsets ``pos0`` (chunk straddles page boundaries), scrambled
     block tables, fp32 and int8 pages."""
-    rng = np.random.RandomState(B * C + ps)
+    rng = np.random.RandomState((4 if B == EDGES else B) * C + ps)
+    if B == EDGES:
+        tile = _pages_per_tile(C * H // KV, ps, hd, 1 if quant else 4,
+                               quant, MP)
+        B, last = _last_positions(rng, B, MP, ps, tile)
+        last = np.maximum(last, C - 1)
+        pos0 = last - (C - 1)
+    else:
+        # pos0 = position of the chunk's FIRST token; last token must fit
+        pos0 = rng.randint(0, MP * ps - C + 1, size=B).astype(np.int32)
+        last = pos0 + C - 1
     ks = jax.random.split(jax.random.PRNGKey(hash((B, C, H, ps)) % 2**31), 3)
     q = jax.random.normal(ks[0], (B, C, H, hd))
     kp = jax.random.normal(ks[1], (P, KV, ps, hd))
     vp = jax.random.normal(ks[2], (P, KV, ps, hd))
-    # pos0 = position of the chunk's FIRST token; last token must fit
-    pos0 = rng.randint(0, MP * ps - C + 1, size=B).astype(np.int32)
-    last = pos0 + C - 1
     bt = _scrambled_tables(rng, B, MP, P, ps, last)
     if quant:
         kp, ksc = _quantize_pages(kp)
@@ -187,6 +224,60 @@ def test_paged_prefill_attn_sweep(B, C, H, KV, hd, ps, MP, P, quant):
     ref = paged_prefill_attn_ref(q, kp, vp, jnp.asarray(bt),
                                  jnp.asarray(pos0),
                                  k_scales=ksc, v_scales=vsc)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("quant,hd", [(False, 128), (True, 128),
+                                      (False, 64), (True, 64)],
+                         ids=["float", "int8", "float-hd64", "int8-hd64"])
+def test_paged_attn_never_reads_dead_pages(quant, hd):
+    """Every page wholly past a row's last live page is NaN (its int8
+    scales, when quantized): decode and chunk prefill still match the
+    oracle over the same pool with those pages finite, so the kernel
+    never reads them, nor lets a stale buffer slot into its sums. At hd
+    128 the kernel copies the pages itself, at hd 64 its pipeline does."""
+    B, H, KV, ps, MP, C = 4, 12, 2, 64, 20, 3
+    tile = _pages_per_tile(H // KV, ps, hd, 1 if quant else 4, quant, MP)
+    rng = np.random.RandomState(5)
+    ks = jax.random.split(jax.random.PRNGKey(23), 4)
+    kp = np.asarray(jax.random.normal(ks[0], (B * MP, KV, ps, hd)))
+    vp = np.asarray(jax.random.normal(ks[1], (B * MP, KV, ps, hd)))
+    bt = rng.permutation(B * MP).reshape(B, MP).astype(np.int32)
+    _, last = _last_positions(rng, EDGES, MP, ps, tile)
+    last = np.maximum(last, C - 1)
+    dead = np.concatenate([bt[b, last[b] // ps + 1:] for b in range(B)])
+    if quant:
+        kp, ksc = _quantize_pages(kp)
+        vp, vsc = _quantize_pages(vp)
+        ksc_nan = np.asarray(ksc).copy()
+        vsc_nan = np.asarray(vsc).copy()
+        ksc_nan[dead] = np.nan
+        vsc_nan[dead] = np.nan
+        clean = dict(k_scales=ksc, v_scales=vsc)
+        nan = dict(k_scales=jnp.asarray(ksc_nan),
+                   v_scales=jnp.asarray(vsc_nan))
+        kp_nan, vp_nan = kp, vp
+    else:
+        kp_nan, vp_nan = kp.copy(), vp.copy()
+        kp_nan[dead] = np.nan
+        vp_nan[dead] = np.nan
+        clean = nan = {}
+    kp, vp = jnp.asarray(kp), jnp.asarray(vp)
+    kp_nan, vp_nan = jnp.asarray(kp_nan), jnp.asarray(vp_nan)
+    bt = jnp.asarray(bt)
+
+    q = jax.random.normal(ks[2], (B, H, hd))
+    pos = jnp.asarray(last)
+    out = paged_decode_attn(q, kp_nan, vp_nan, bt, pos, **nan)
+    ref = paged_decode_attn_ref(q, kp, vp, bt, pos, **clean)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-4, atol=2e-4)
+
+    qc = jax.random.normal(ks[3], (B, C, H, hd))
+    pos0 = jnp.asarray(last - (C - 1))
+    out = paged_prefill_attn(qc, kp_nan, vp_nan, bt, pos0, **nan)
+    ref = paged_prefill_attn_ref(qc, kp, vp, bt, pos0, **clean)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
 

@@ -344,6 +344,26 @@ def test_paged_mixed_pool_batched_controller_contract(setup):
     assert sorted(sched._kappa_pool.free) == list(range(12))
 
 
+def test_paged_kv_page_counters(setup):
+    """``kv_pages_live`` counts the pages the decode kernel walks, each
+    row's up to its position (pos // ps + 1, an idle row's one page),
+    and ``kv_pages_table`` the block-table slots (rows x max_pages), on
+    every decode tick."""
+    cfg, params, kcfg, prompts, _ = setup
+    sched = PagedScheduler(params, cfg, kcfg, rows=4, max_seq=32,
+                           page_size=4, num_pages=32, method="greedy",
+                           eos_id=-1, bos_id=tok.BOS)
+    for i, p in enumerate(prompts[:2]):               # 7 and 9 tokens
+        sched.submit(p, jax.random.PRNGKey(i), max_new=8)
+    # decode tick k runs the rows at positions 7 + k and 9 + k, and two
+    # idle rows at 0: pages (7+k)//4 + (9+k)//4 + 2 + 2
+    live_per_tick = [7, 8, 8, 9]
+    for k, live in enumerate(live_per_tick):
+        sched.tick()
+        assert sched.counters["kv_pages_live"] == sum(live_per_tick[:k + 1])
+        assert sched.counters["kv_pages_table"] == (k + 1) * 4 * 8
+
+
 def test_paged_out_of_pages_refusal(setup):
     """A request whose worst case exceeds the whole pool is refused at
     submit; one that merely has to wait is served once pages free up."""
