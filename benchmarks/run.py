@@ -7,7 +7,6 @@
   schedule_ablation — §4.2: linear vs cosine vs step pruning
   weight_ablation   — §4.1: (w_KL, w_C, w_H) mixes
   kernel_bench      — fused-score traffic arithmetic
-  throughput        — sequential vs contiguous vs paged serving tok/s
 
 Usage: PYTHONPATH=src python -m benchmarks.run [table ...]
 Env:   BENCH_FULL=1 for paper-scale N∈{5,10,20} + longer training.
@@ -29,7 +28,6 @@ from benchmarks import (
     kernel_bench,
     memory_ratio,
     schedule_ablation,
-    throughput,
     token_ratio,
     weight_ablation,
 )
@@ -43,7 +41,6 @@ TABLES = {
     "weight_ablation": weight_ablation,
     "horizon_ablation": horizon_ablation,
     "kernel_bench": kernel_bench,
-    "throughput": throughput,
 }
 
 

@@ -145,7 +145,7 @@ def serve(params, kv_dtype: str, label: str, *, traced: bool) -> dict:
     t0 = time.perf_counter()
     out = serve_eval(ARCH, "kappa", n=N_BRANCHES, problems=PROBLEMS,
                      max_new=MAX_NEW, params=params, full_width=True,
-                     seed=SEED, paged=True, page_size=PAGE_SIZE,
+                     seed=SEED, page_size=PAGE_SIZE,
                      prefill_chunk=PREFILL_CHUNK, frontend_serve=True,
                      stream=True, kv_dtype=kv_dtype, verbose=False,
                      dataset_kw=dict(min_steps=5, max_steps=5))

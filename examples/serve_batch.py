@@ -37,22 +37,13 @@ print(f"\nKAPPA vs BoN (N=10): token reduction "
       f"memory reduction {1 - kap10['peak_memory_mb']/bon10['peak_memory_mb']:.1%}, "
       f"accuracy delta {kap10['accuracy'] - bon10['accuracy']:+.3f}")
 
-# the same prompts through the continuous-batching row pool: identical
+# the same prompts through the continuous-batching paged pool: identical
 # outputs (same per-request keys), but pruned rows are backfilled with
-# queued prefills instead of idling
-seq5 = next(r for r in rows if r["method"] == "kappa" and r["n"] == 5)
-cb5 = serve_eval(args.arch, "kappa", n=5, problems=args.problems,
-                 params=params, cfg=cfg, verbose=False, scheduler=True)
-print(f"continuous batching (N=5, rows=10): {cb5['tokens_per_s']:.1f} tok/s, "
-      f"{cb5['requests_per_s']:.2f} req/s, "
-      f"row utilization {cb5['row_utilization']:.2f} "
-      f"(sequential wall {seq5['time_s']:.1f}s vs {cb5['time_s']:.1f}s)")
-
-# the paged pool: same tokens again, but KV reservations are per-request
-# pages, pruning frees pages instantly, and more rows share the budget
+# queued prefills instead of idling, KV reservations are per-request
+# pages, and pruning frees pages instantly
 pg5 = serve_eval(args.arch, "kappa", n=5, problems=args.problems,
                  params=params, cfg=cfg, verbose=False, scheduler=True,
-                 paged=True, page_size=16, sched_rows=20)
+                 page_size=16, sched_rows=20)
 print(f"paged pool        (N=5, rows=20): {pg5['tokens_per_s']:.1f} tok/s, "
       f"{pg5['requests_per_s']:.2f} req/s, "
       f"page utilization {pg5['page_utilization']:.2f} "
@@ -64,15 +55,15 @@ print(f"paged pool        (N=5, rows=20): {pg5['tokens_per_s']:.1f} tok/s, "
 # token-for-token equal to the terminal results
 fe5 = serve_eval(args.arch, "kappa", n=5, problems=args.problems,
                  params=params, cfg=cfg, verbose=False, scheduler=True,
-                 paged=True, page_size=16, sched_rows=20,
+                 page_size=16, sched_rows=20,
                  frontend_serve=True, stream=True)
 print(f"streaming frontend(N=5, rows=20): {fe5['tokens_per_s']:.1f} tok/s, "
       f"{fe5['requests_per_s']:.2f} req/s (wall {fe5['time_s']:.1f}s)")
 
 # per-terminal-status summary with goodput (OK tokens per wall second —
-# the number the SLO-adaptive admission sweep optimizes): with no
+# the number the SLO-adaptive admission controller protects): with no
 # faults, deadlines, or queue bound every request should land in OK
-for name, r in [("continuous", cb5), ("paged", pg5), ("frontend", fe5)]:
+for name, r in [("paged", pg5), ("frontend", fe5)]:
     sc = r["status_counts"]
     print(f"{name:10s} statuses: "
           + " ".join(f"{k}={sc.get(k, 0)}"

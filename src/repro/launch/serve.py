@@ -6,8 +6,10 @@ metric columns.
       --method kappa --n 5 --problems 20 [--ckpt ckpt.msgpack]
 
 ``--scheduler`` serves the same prompts through the continuous-batching
-row pool (repro.serving.scheduler) instead of one at a time, and adds
-throughput columns (requests/s, tokens/s, row utilization).
+paged pool (repro.serving.scheduler.PagedScheduler) instead of one at a
+time, and adds throughput columns (requests/s, tokens/s, row and page
+utilization). ``--page-size``, ``--num-pages``, ``--prefill-chunk``,
+``--prefix-cache`` and ``--kv-dtype`` shape that pool.
 
 ``--frontend`` drives the same pool through the async streaming
 front-end (repro.serving.frontend) instead of batch ``run()``: every
@@ -23,7 +25,7 @@ is given. That size is for an accelerator:
 
   python -m repro.launch.serve --arch deepseek-r1-distill-qwen-1.5b \
       --full-width --method kappa --n 5 --problems 4 --max-new 128 \
-      --paged --prefill-chunk 8 --frontend --stream
+      --prefill-chunk 8 --frontend --stream
 """
 from __future__ import annotations
 
@@ -46,7 +48,7 @@ from repro.serving import engine
 from repro.serving import faults as faults_lib
 from repro.serving import strategies
 from repro.serving.frontend import ServingFrontend
-from repro.serving.scheduler import ContinuousBatchingScheduler, PagedScheduler
+from repro.serving.scheduler import PagedScheduler
 from repro.training import checkpoint
 
 METHODS = {
@@ -105,7 +107,7 @@ def serve_eval(arch: str, method: str, *, n: int = 5, problems: int = 20,
                kcfg_kw: dict | None = None, dataset_kw: dict | None = None,
                params=None, cfg=None, verbose: bool = True,
                scheduler: bool = False, sched_rows: int | None = None,
-               paged: bool = False, page_size: int = 64,
+               page_size: int = 64,
                num_pages: int | None = None,
                prefill_chunk: int | None = None,
                prefix_cache: bool = False,
@@ -139,8 +141,8 @@ def serve_eval(arch: str, method: str, *, n: int = 5, problems: int = 20,
               horizon=8, window=8, mom_buckets=4)
     kw.update(kcfg_kw or {})
     kcfg = KappaConfig(**kw)
-    # paged pool / streaming front-end both imply the scheduler path
-    scheduler = scheduler or paged or frontend_serve
+    # the streaming front-end implies the scheduler path
+    scheduler = scheduler or frontend_serve
     dkw = dict(min_steps=2, max_steps=5, num_ops=2, max_operand=10)
     dkw.update(dataset_kw or {})
     test = tasks.make_dataset(seed, problems, **dkw)
@@ -154,17 +156,12 @@ def serve_eval(arch: str, method: str, *, n: int = 5, problems: int = 20,
         fan_out = factory().rows(kcfg)
         plan = (faults_lib.parse_fault_spec(inject_faults)
                 if inject_faults else None)
-        sched_kw = dict(rows=sched_rows or 2 * fan_out, max_seq=max_seq,
-                        method=method, eos_id=tok.EOS, bos_id=tok.BOS,
-                        frontend=fe, strategy_factory=factory,
-                        prefill_chunk=prefill_chunk, faults=plan,
-                        max_queue=max_queue)
-        if paged:
-            sched = PagedScheduler(params, cfg, kcfg, page_size=page_size,
-                                   num_pages=num_pages,
-                                   prefix_cache=prefix_cache, **sched_kw)
-        else:
-            sched = ContinuousBatchingScheduler(params, cfg, kcfg, **sched_kw)
+        sched = PagedScheduler(
+            params, cfg, kcfg, rows=sched_rows or 2 * fan_out,
+            max_seq=max_seq, page_size=page_size, num_pages=num_pages,
+            method=method, eos_id=tok.EOS, bos_id=tok.BOS, frontend=fe,
+            strategy_factory=factory, prefill_chunk=prefill_chunk,
+            prefix_cache=prefix_cache, faults=plan, max_queue=max_queue)
         if frontend_serve:
             gens = _serve_frontend(sched, test, deadline_s=deadline_s,
                                    stream=stream)
@@ -229,15 +226,14 @@ def serve_eval(arch: str, method: str, *, n: int = 5, problems: int = 20,
         # batch run() path and the streaming front-end path
         ok_tokens = sum(r.logical_tokens for r in gens if r.status == "OK")
         out["goodput_tokens_per_s"] = ok_tokens / max(tp["time_s"], 1e-9)
-        if paged:
-            out["page_utilization"] = tp["page_utilization"]
-            out["page_peak"] = tp["page_peak"]
-            out["preemptions"] = tp["preemptions"]
-            if prefix_cache:
-                out["prefix_hit_rate"] = tp["prefix_hit_rate"]
-                out["prefix_tokens_saved"] = tp["prefix_tokens_saved"]
-                out["prefix_evictions"] = tp["prefix_evictions"]
-                out["prefix_pinned_pages"] = tp["prefix_pinned_pages"]
+        out["page_utilization"] = tp["page_utilization"]
+        out["page_peak"] = tp["page_peak"]
+        out["preemptions"] = tp["preemptions"]
+        if prefix_cache:
+            out["prefix_hit_rate"] = tp["prefix_hit_rate"]
+            out["prefix_tokens_saved"] = tp["prefix_tokens_saved"]
+            out["prefix_evictions"] = tp["prefix_evictions"]
+            out["prefix_pinned_pages"] = tp["prefix_pinned_pages"]
     if verbose:
         line = (f"{arch} {method:7s} N={n:3d} acc={out['accuracy']:.3f} "
                 f"total_toks={out['total_tokens']:8.1f} "
@@ -248,7 +244,7 @@ def serve_eval(arch: str, method: str, *, n: int = 5, problems: int = 20,
                      f"goodput={out['goodput_tokens_per_s']:.1f} tok/s "
                      f"{out['requests_per_s']:.2f} req/s "
                      f"util={out['row_utilization']:.2f}")
-        if paged and prefix_cache:
+        if scheduler and prefix_cache:
             line += (f" | prefix: hit={out['prefix_hit_rate']:.2f} "
                      f"saved={out['prefix_tokens_saved']} "
                      f"evict={out['prefix_evictions']} "
@@ -270,12 +266,11 @@ def serve_eval(arch: str, method: str, *, n: int = 5, problems: int = 20,
         assert out["faults_injected"] > 0, \
             "fault plan injected nothing — raise its probabilities"
         assert out["retries"] > 0, "no fault-triggered retries recorded"
-        if paged:
-            if sched.pcache is not None:
-                sched.pcache.drop()
-            assert sched.alloc.free_count == sched.num_pages, \
-                f"leaked pages: {sched.num_pages - sched.alloc.free_count}"
-            assert int(sched.alloc.pinned.sum()) == 0, "leaked pins"
+        if sched.pcache is not None:
+            sched.pcache.drop()
+        assert sched.alloc.free_count == sched.num_pages, \
+            f"leaked pages: {sched.num_pages - sched.alloc.free_count}"
+        assert int(sched.alloc.pinned.sum()) == 0, "leaked pins"
         if verbose:
             print("  chaos smoke: zero leaked pages/pins, "
                   f"{out['retries']} retries survived")
@@ -291,16 +286,16 @@ def main(argv=None):
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--max-new", type=int, default=48)
     ap.add_argument("--scheduler", action="store_true",
-                    help="serve through the continuous-batching row pool")
+                    help="serve through the continuous-batching paged "
+                         "KV pool")
     ap.add_argument("--rows", type=int, default=None,
                     help="pool rows for --scheduler (default 2x fan-out)")
-    ap.add_argument("--paged", action="store_true",
-                    help="use the paged KV pool scheduler (implies --scheduler)")
     ap.add_argument("--page-size", type=int, default=64,
-                    help="token slots per KV page for --paged")
+                    help="token slots per KV page (scheduler paths only)")
     ap.add_argument("--num-pages", type=int, default=None,
-                    help="allocatable KV pages for --paged (default: no "
-                         "page pressure, rows*max_seq/page_size)")
+                    help="allocatable KV pages (scheduler paths only; "
+                         "default: no page pressure, "
+                         "rows*max_seq/page_size)")
     ap.add_argument("--kv-dtype", default="model",
                     choices=("model", "int8"),
                     help="KV cache dtype: 'model' keeps the model dtype; "
@@ -309,7 +304,8 @@ def main(argv=None):
                          "HBM byte")
     ap.add_argument("--prefix-cache", action="store_true",
                     help="enable the cross-request radix prefix cache "
-                         "(--paged only): admissions alias previously "
+                         "(scheduler paths with --prefill-chunk only): "
+                         "admissions alias previously "
                          "published prompt/winner pages and skip their "
                          "prefill")
     ap.add_argument("--prefill-chunk", type=int, default=None,
@@ -344,8 +340,8 @@ def main(argv=None):
     enable_compile_cache()
     serve_eval(args.arch, args.method, n=args.n, problems=args.problems,
                ckpt=args.ckpt, max_new=args.max_new,
-               scheduler=args.scheduler or args.paged, sched_rows=args.rows,
-               paged=args.paged, page_size=args.page_size,
+               scheduler=args.scheduler, sched_rows=args.rows,
+               page_size=args.page_size,
                num_pages=args.num_pages, prefill_chunk=args.prefill_chunk,
                prefix_cache=args.prefix_cache,
                inject_faults=args.inject_faults, max_queue=args.max_queue,

@@ -51,32 +51,10 @@ def gather_batch(cache, idx):
 
 def scatter_batch(pool, idx, sub):
     """Write ``sub``'s branch rows into pool rows ``idx`` — the inverse
-    of :func:`gather_batch`, used by the continuous-batching scheduler to
-    install a freshly prefilled request into free slots of its fixed
-    (rows, max_seq) device pool (DESIGN.md §4)."""
+    of :func:`gather_batch`."""
     return _map_batched2(pool, sub,
                          lambda a, b: a.at[:, idx].set(b),
                          lambda a, b: a.at[idx].set(b))
-
-
-def scatter_batch_prefix(pool, idx, sub):
-    """Like :func:`scatter_batch`, but ``sub``'s leaves may be *shorter*
-    than the pool's on their non-batch axes (a prompt-sized prefill
-    cache installed into max_seq-sized pool rows): each leaf writes only
-    its own extent, leaving the rows' tails untouched. Stale data beyond
-    a request's written positions is never read — decode writes position
-    p before attending with ``kv_pos <= p``, and ring-slot validity
-    masks unwritten slots. ``sub`` may be batch-1 (broadcast into all
-    ``idx`` rows) or match ``len(idx)``."""
-    def st(a, b):
-        sl = (slice(None), idx) + tuple(slice(0, s) for s in b.shape[2:])
-        return a.at[sl].set(b)
-
-    def rm(a, b):
-        sl = (idx,) + tuple(slice(0, s) for s in b.shape[1:])
-        return a.at[sl].set(b)
-
-    return _map_batched2(pool, sub, st, rm)
 
 
 def broadcast_batch(cache, n: int):
@@ -148,24 +126,13 @@ def used_cache_bytes(cfg, rows: int, pos: int, max_seq: int, *,
     return int(total)
 
 
-def per_request_bytes(cfg, rows_pos: Dict[Any, tuple], max_seq: int
-                      ) -> Dict[Any, int]:
-    """Per-request paged-view byte accounting over a shared row pool:
-    ``rows_pos`` maps request id -> (occupied rows, current pos). Each
-    request is charged only for the slots it owns, referenced up to its
-    own position — the scheduler's analogue of the single-request
-    ``used_cache_bytes`` accounting."""
-    return {rid: used_cache_bytes(cfg, r, p, max_seq)
-            for rid, (r, p) in rows_pos.items()}
-
-
 # ----------------------------------------------------------- paged pool
 #
-# DESIGN.md §5: the paged scheduler replaces the contiguous (rows,
-# max_seq) reservation with fixed-size pages handed out from a free
-# list. Freeing a pruned branch returns its pages immediately — no
-# gather/compaction on the scheduler path — and admission is counted in
-# pages, so rows of different lengths share the pool.
+# DESIGN.md §5: instead of reserving max_seq slots for every row, the
+# scheduler hands out fixed-size pages from a free list. Freeing a
+# pruned branch returns its pages immediately — no gather/compaction on
+# the scheduler path — and admission is counted in pages, so rows of
+# different lengths share the pool.
 
 
 class PageAllocator:
@@ -641,7 +608,9 @@ def install_rows_aux(cfg, pool, row_idx, aux):
     fan-out. Global-attention leaves are untouched — their prompt K/V
     already lives in allocator-owned pages (DESIGN.md §6). Aux leaves
     shorter than the pool's (a ring sized to a short prompt) write only
-    their own extent, like :func:`scatter_batch_prefix`."""
+    their own extent, leaving the rows' tails untouched: stale data
+    beyond a request's written positions is never read, as ring-slot
+    validity masks unwritten slots."""
     def per_entry(bt, is_stack, entry, aux_entry):
         if bt == "global":
             return entry

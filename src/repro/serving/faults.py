@@ -1,12 +1,12 @@
-"""Seeded deterministic fault injection for the serving schedulers
+"""Seeded deterministic fault injection for the serving scheduler
 (DESIGN.md §8).
 
 A :class:`FaultPlan` is a pure function of ``(seed, site, tick)``: every
 draw comes from ``np.random.default_rng([seed, site_id, tick])``, so a
 fault schedule is reproducible across runs, idempotent if a site is
 consulted twice in one tick, and independent of consultation *order*
-(the property that lets the two scheduler backends — whose tick counts
-differ — each get a deterministic schedule from one seed).
+(the property that lets pool configurations whose tick counts differ
+each get a deterministic schedule from one seed).
 
 Three injection sites, mirroring the real failure classes a serving
 pool sees:

@@ -1,8 +1,8 @@
-"""Async streaming front-end over the tick schedulers (DESIGN.md §9).
+"""Async streaming front-end over the tick scheduler (DESIGN.md §9).
 
-``ServingFrontend`` owns a scheduler (contiguous or paged, any strategy
-mix) and drives its incremental ``step()`` surface from a background
-task, streaming each request's committed tokens back as
+``ServingFrontend`` owns a scheduler (any strategy mix) and drives its
+incremental ``step()`` surface from a background task, streaming each
+request's committed tokens back as
 :class:`~repro.serving.scheduler.TokenEvent` objects the moment the
 tick that produced them retires. Two interchangeable drive backends:
 

@@ -56,8 +56,8 @@ def _picked_lp(logits, tokens):
 _picked_logprob_jit = jax.jit(_picked_lp)
 
 # device-dispatch counters: schedulers promise ONE fused sampling call
-# per tick regardless of active-request count — tests and the
-# throughput-benchmark breakdown assert against these (reset freely)
+# per tick regardless of active-request count — tests assert against
+# these (reset freely)
 DISPATCHES = {"sample_rows": 0, "picked_logprob": 0}
 
 

@@ -1,61 +1,49 @@
-"""Continuous-batching multi-request schedulers (DESIGN.md §4–§5).
+"""The continuous-batching multi-request scheduler (DESIGN.md §4–§5).
 
 The sequential engine serves one prompt at a time: N branch rows, pruned
 to 1 by KAPPA/ST-BoN, then a long single-row tail to EOS — poor device
-utilization exactly when pruning succeeds. These schedulers turn freed
-capacity into throughput, the serving-level payoff the early-pruning
-papers point at (ST-BoN, Wang et al. 2025; Bi et al. 2025). Two pool
-backends share one driver:
+utilization exactly when pruning succeeds. :class:`PagedScheduler`
+turns freed capacity into throughput, the serving-level payoff the
+early-pruning papers point at (ST-BoN, Wang et al. 2025; Bi et al.
+2025). Its KV lives in one paged pool (DESIGN.md §5): global attention
+layers share a page pool, rows hold ``(max_pages,)`` block tables,
+fan-out branches share the prompt pages copy-on-write, decode pages are
+allocated lazily at page-boundary crossings (with youngest-admitted
+preemption when the pool runs dry), pruning drops page references the
+moment it happens, and queued requests are admitted shortest-job-first
+with bounded bypass among those that fit. With ``prefix_cache=True`` a
+cross-request radix tree (DESIGN.md §7) pins completed requests'
+prompt/winner pages so later admissions alias them and prefill only the
+uncached tail; under pressure, least-recently-hit cached pages are
+evicted before any request is preempted.
 
-  * :class:`ContinuousBatchingScheduler` — PR 1's contiguous
-    ``(rows, max_seq)`` device pool with FIFO admission counted in rows.
-    Every row reserves (and streams through attention) ``max_seq`` KV
-    slots regardless of the request's actual length.
-  * :class:`PagedScheduler` — a paged KV pool (DESIGN.md §5): global
-    attention layers share a page pool, rows hold ``(max_pages,)`` block
-    tables, fan-out branches share the prompt pages copy-on-write,
-    decode pages are allocated lazily at page-boundary crossings (with
-    youngest-admitted preemption when the pool runs dry), pruning drops
-    page references the moment it happens, and queued requests are
-    admitted shortest-job-first with bounded bypass among those that
-    fit. With ``prefix_cache=True`` a cross-request radix tree
-    (DESIGN.md §7) pins completed requests' prompt/winner pages so
-    later admissions alias them and prefill only the uncached tail;
-    under pressure, least-recently-hit cached pages are evicted
-    before any request is preempted.
+Per tick:
 
-Shared driver behaviour per tick:
-
-  * admit whatever the backend's policy allows. With ``prefill_chunk``
-    set, admission enters a **PREFILLING** state (DESIGN.md §6): the
-    request owns its row slots (and, paged, the pages written so far)
-    and advances one prompt chunk per tick — the oldest one *inside*
-    the fused decode dispatch itself — so decode rows never stall for
-    more than one chunk's latency on a long-prompt admission; the final
-    chunk's logits are bitwise-equal to the one-shot prefill and feed
-    the same strategy start path. Without chunking (or for
+  * admit whatever fits. With ``prefill_chunk`` set, admission enters a
+    **PREFILLING** state (DESIGN.md §6): the request owns its row slots
+    and the pages written so far and advances one prompt chunk per tick
+    — inside the fused decode dispatch itself — so decode rows never
+    stall for more than one chunk's latency on a long-prompt admission;
+    the final chunk's logits are bitwise-equal to the one-shot prefill
+    and feed the same strategy start path. Without chunking (or for
     frontend/enc-dec requests) admission falls back to a one-shot
-    batch-1 prefill through a transient cache sized to the prompt (the
-    contiguous pool broadcasts inside its install scatter, the paged
-    pool aliases shared prompt pages copy-on-write across the N branch
-    block tables);
+    batch-1 prefill through a transient cache sized to the prompt, whose
+    prompt pages are aliased copy-on-write across the N branch block
+    tables;
   * one fused decode step over the whole pool with per-row positions;
   * ONE fused sampler dispatch for every active request's rows
-    (per-row RNG keys — :func:`repro.serving.sampler.sample_rows`)
-    instead of a per-request ``sample_step`` call. Each active
-    request's RNG stream lives in a device table while it decodes, and
-    the sampler program derives every row's key from it, so the tick
-    has no per-request key work on the host;
+    (per-row RNG keys — :func:`repro.serving.sampler.sample_rows`).
+    Each active request's RNG stream lives in a device table while it
+    decodes, and the sampler program derives every row's key from it,
+    so the tick has no per-request key work on the host;
   * ONE pooled KAPPA-controller dispatch for every active kappa request
     (:class:`repro.serving.strategies.PooledKappaController`): the
     stacked controller state consumes the pool logits and just-sampled
     tokens device-to-device, and its alive/traj/cutoff outputs ride the
-    tick's single blocking transfer — replacing the per-request
-    ``kappa_step`` dispatch + ``np.asarray(alive)`` sync that made the
-    controller the bottleneck (dispatch/sync counters in ``counters``
-    assert the ≤1-per-tick contract; ``tick_time`` records host seconds
-    per tick phase, each also a ``serve.<phase>`` profiler span —
-    ``serving/spans.py``);
+    tick's single blocking transfer (dispatch/sync counters in
+    ``counters`` assert the ≤1-per-tick contract; ``tick_time`` records
+    host seconds per tick phase, each also a ``serve.<phase>`` profiler
+    span — ``serving/spans.py``);
   * per-request strategies (repro.serving.strategies) drive pruning and
     compaction decisions on their own row groups (host-side, from the
     published controller mirrors); freed capacity is backfilled by
@@ -68,7 +56,7 @@ Shared driver behaviour per tick:
 Equivalence guarantee: the batched decode step is row-independent, the
 per-row-keyed sampler is row-independent, and the host-side per-request
 logic is shared verbatim with the engine loop — so with the same
-per-request keys and the same ``max_seq`` both schedulers reproduce the
+per-request keys and the same ``max_seq`` the scheduler reproduces the
 sequential engine token for token (tests/test_scheduler.py,
 tests/test_paged.py).
 
@@ -94,7 +82,7 @@ injectable ``clock=`` callable (default ``time.monotonic``) so latency
 behaviour is testable without sleeping; retry backoff stays tick-counted
 and needs no clock. :meth:`snapshot` reads the per-window TTFT/ITL
 percentiles and goodput counters the SLO controller (``serving.slo``)
-and the open-loop arrival sweeps consume.
+consumes.
 """
 from __future__ import annotations
 
@@ -125,7 +113,6 @@ class Unservable(ValueError):
     Subclasses ValueError so callers that guarded the old assertions
     keep working."""
 
-_scatter = jax.jit(cache_lib.scatter_batch_prefix, donate_argnums=(0,))
 _install_shared = jax.jit(cache_lib.install_paged_shared,
                           static_argnums=(0, 6), donate_argnums=(1,))
 _paged_step = jax.jit(decode_step, static_argnums=(1,), donate_argnums=(4,))
@@ -157,14 +144,13 @@ class _Queued:
 @dataclasses.dataclass
 class _Prefill:
     """A request in the PREFILLING state (DESIGN.md §6): it owns its row
-    slots (and, in the paged backend, the pages written so far through
-    slot[0]'s block table) and advances one prompt chunk per tick inside
-    the same scheduler tick as the active decode rows."""
+    slots and the pages written so far through slot[0]'s block table,
+    and advances one prompt chunk per tick inside the same scheduler
+    tick as the active decode rows."""
     item: _Queued
     slots: List[int]
     filled: int = 0            # prompt tokens written so far
-    cache1: object = None      # contiguous backend: prompt-sized side cache
-    aux: object = None         # paged backend: batch-1 per-row-family state
+    aux: object = None         # batch-1 per-row-family state
 
 
 @dataclasses.dataclass
@@ -186,21 +172,90 @@ class TokenEvent:
     result: Optional[GenResult] = None
 
 
-class _SchedulerBase:
-    """Queue + row-slot lifecycle + fused tick, independent of how KV
-    storage is reserved. Subclasses implement the storage policy."""
+class PagedScheduler:
+    """The serving scheduler (DESIGN.md §4–§5): the request queue, the
+    row-slot lifecycle and the fused tick over one paged KV pool.
+
+    Global-attention KV lives in a shared page pool; each row addresses
+    it through a ``(max_pages,)`` block table. Fan-out branches *share*
+    the fully-written prompt pages copy-on-write: admission allocates
+    them once, aliases them into all N branch tables, and gives each
+    branch a private copy of the partially-written boundary page (where
+    divergent decode writes land) plus one decode page — so admission
+    costs ``prompt_pages + N × (1 + boundary)`` pages instead of
+    ``N × ceil(need / page_size)``. Decode pages are acquired *lazily*,
+    one page per row as its position crosses a page boundary; when the
+    free list runs dry the scheduler preempts the youngest-admitted
+    request (pages freed, request requeued and replayed from its
+    original RNG — token-for-token identical to an un-preempted run)
+    instead of deadlocking. Pruning a branch drops its page references
+    immediately; a page returns to the free heap when its last
+    reference goes.
+
+    Queued requests are admitted shortest-job-first among those whose
+    rows *and* initial pages fit (FIFO tie-break on equal need), with
+    bounded bypass: once the queue head has been bypassed
+    ``max_bypass`` times, it is admitted next or nothing is — a steady
+    stream of short submissions can no longer starve a long request.
+
+    Parameters
+    ----------
+    rows : row slots (block tables / position vector entries). Must be
+        >= the fan-out of a single request (``strategy.rows(kcfg)``).
+    max_seq : upper bound on any request's ``prompt + n_prefix + max_new``
+        (rounded up to a page multiple internally).
+    page_size : token slots per page, the allocation unit. The paged
+        kernel sizes its tile of pages from a VMEM budget, not from this.
+    num_pages : allocatable pages in the pool — the real memory knob.
+        Defaults to ``rows * max_seq / page_size`` (no page pressure);
+        set lower to serve more rows than reserving ``max_seq`` slots
+        for every row would allow.
+    page_budget_bytes : alternative memory knob — an HBM byte budget
+        for the global-layer page pool, converted to ``num_pages`` via
+        allocator-truth :func:`cache.page_bytes` (so an int8
+        ``kv_cache_dtype`` yields ≈2× the pages of fp32/bf16 under the
+        same budget). Mutually exclusive with ``num_pages``.
+    method : one of "greedy" | "bon" | "stbon" | "kappa"; or pass
+        ``strategy_factory`` for custom construction (e.g. ST-BoN with a
+        non-default buffer window).
+    max_bypass : SJF aging bound (see above).
+    prefix_cache : enable the cross-request radix prefix cache
+        (DESIGN.md §7). Completed/preempted requests publish their
+        fully-written prompt pages (and the winner's generated prefix)
+        into a radix tree that pins them in the allocator; later
+        admissions alias every matched page and chunk-prefill only the
+        uncached tail. Requires chunked admission (``prefill_chunk``)
+        and an all-global layer pattern — anything else silently keeps
+        the cache off (aux ring/recurrent state cannot be recovered
+        from pages, and only the chunked path can resume a prefill at a
+        nonzero offset).
+    """
 
     def __init__(self, params, cfg: ModelConfig, kcfg: KappaConfig, *,
-                 rows: int, max_seq: int, method: str = "kappa",
+                 rows: int, max_seq: int, page_size: int = 64,
+                 num_pages: Optional[int] = None, method: str = "kappa",
                  eos_id: int, bos_id: int = 0, frontend=None,
-                 strategy_factory: Optional[Callable[[], strategies.DecodeStrategy]] = None,
-                 fused_sampling: bool = True,
-                 prefill_chunk: Optional[int] = None,
+                 strategy_factory: Optional[Callable[
+                     [], strategies.DecodeStrategy]] = None,
+                 max_bypass: int = 4, prefill_chunk: Optional[int] = None,
+                 prefix_cache: bool = False,
                  faults: Optional[faults_lib.FaultPlan] = None,
                  max_retries: int = 3, retry_backoff: int = 2,
                  max_queue: Optional[int] = None,
                  clock: Optional[Callable[[], float]] = None,
-                 event_sink: Optional[Callable[[TokenEvent], None]] = None):
+                 event_sink: Optional[Callable[[TokenEvent], None]] = None,
+                 page_budget_bytes: Optional[int] = None):
+        max_seq = -(-max_seq // page_size) * page_size
+        if page_budget_bytes is not None:
+            if num_pages is not None:
+                raise ValueError("pass num_pages or page_budget_bytes, "
+                                 "not both")
+            num_pages = page_budget_bytes \
+                // cache_lib.page_bytes(cfg, page_size)
+            if num_pages < 1:
+                raise ValueError(
+                    f"page_budget_bytes={page_budget_bytes} below one "
+                    f"page ({cache_lib.page_bytes(cfg, page_size)}B)")
         self.params = params
         self.cfg = cfg
         self.kcfg = kcfg
@@ -211,10 +266,6 @@ class _SchedulerBase:
         self.frontend = frontend
         self.strategy_factory = strategy_factory or (
             lambda: strategies.make_strategy(method))
-        # False = PR 1 dispatch pattern (one sample_step call + host sync
-        # per request per tick) — kept as a benchmark baseline; tokens
-        # are identical either way (sample_rows is row-independent)
-        self.fused_sampling = fused_sampling
         self.n_prefix = engine._n_prefix(cfg)
 
         need = self.strategy_factory().rows(kcfg)
@@ -247,14 +298,12 @@ class _SchedulerBase:
         self._fused_chunk_out = None         # fused decode dispatch
         self.active: Dict[int, tuple] = {}   # rid -> (RequestState, slots)
         self._slots_dev: Dict[int, object] = {}  # rid -> device slot idx
-        # fused sampling: each active request's RNG stream lives in one
-        # slot of a device table (raw threefry key data) while it
-        # decodes; the sampler program advances it and derives the row
-        # keys. The per-row operands change only when rows do.
-        self._streams = None
-        if fused_sampling:
-            sampler.check_partitionable()
-            self._streams = jnp.zeros((rows, 2), jnp.uint32)
+        # each active request's RNG stream lives in one slot of a device
+        # table (raw threefry key data) while it decodes; the sampler
+        # program advances it and derives the row keys. The per-row
+        # operands change only when rows do.
+        sampler.check_partitionable()
+        self._streams = jnp.zeros((rows, 2), jnp.uint32)
         self._free_streams: List[int] = list(range(rows))
         self._stream_of: Dict[int, int] = {}  # rid -> stream slot
         self._stream_adv = np.zeros((rows,), bool)
@@ -282,6 +331,12 @@ class _SchedulerBase:
             "sampler_dispatches": 0, "host_syncs": 0, "preemptions": 0,
             "retries": 0, "failures": 0, "cancelled": 0, "timeouts": 0,
             "shed": 0, "faults_injected": 0,
+            "prefix_hits": 0, "prefix_misses": 0,
+            "prefix_tokens_saved": 0, "prefix_evictions": 0,
+            "fused_chunks": 0,
+            # pages the decode kernel walks (each row's up to its
+            # position) against its block-table slots (rows x max_pages)
+            "kv_pages_live": 0, "kv_pages_table": 0,
         }
         # request-lifecycle hardening (DESIGN.md §8): fault plan, bounded
         # retry-with-backoff, and the bounded admission queue
@@ -335,50 +390,28 @@ class _SchedulerBase:
         self._win_itl: List[float] = []
         self._win_counts = {"completed": 0, "ok": 0, "ok_tokens": 0,
                             "shed": 0}
+        self.page_size = page_size
+        self.max_pages = max_seq // page_size
+        self.num_pages = num_pages if num_pages is not None \
+            else rows * self.max_pages
+        self.max_bypass = max_bypass
+        self.alloc = cache_lib.PageAllocator(self.num_pages, page_size,
+                                             rows, self.max_pages,
+                                             fault_plan=self.faults)
+        self.pool = init_paged_cache(cfg, rows, self.num_pages, page_size,
+                                     max_seq)
+        # radix prefix cache: only sound when every layer's KV is page-
+        # resident (all-global) and admission can resume a prefill at
+        # the cached extent (chunked)
+        self.pcache: Optional[cache_lib.RadixPrefixCache] = None
+        if prefix_cache and self._chunked_ok \
+                and all(bt == "global" for bt in cfg.block_types()):
+            self.pcache = cache_lib.RadixPrefixCache(self.alloc, page_size)
+        self._page_ticks = 0                 # Σ pages in use over ticks
+        self._page_peak = 0                  # max pages in use at any tick
+        self._bt_dev = None                  # device block tables (cached)
 
-    # ----------------------------------------------------- storage hooks
-
-    def _check_servable(self, item: _Queued) -> None:
-        """Raise if the request can never be admitted."""
-
-    def _admissible(self, item: _Queued) -> bool:
-        """Whether the request fits the free capacity right now."""
-        raise NotImplementedError
-
-    def _select_admit(self) -> Optional[int]:
-        """Queue index to admit next, or None. Defines the policy."""
-        raise NotImplementedError
-
-    def _install(self, slots: List[int], item: _Queued, sub1) -> None:
-        """Install the batch-1 prefilled sub-cache into the row slots
-        (fanning out / aliasing is the backend's storage policy)."""
-        raise NotImplementedError
-
-    def _release_storage(self, slots: List[int]) -> None:
-        """Return the slots' KV reservation (pages / nothing extra)."""
-
-    def _publish_prompt_pages(self, prompt: np.ndarray, slot: int,
-                              upto: int) -> None:
-        """Teardown hook, called BEFORE a departing (preempted /
-        cancelled / timed-out) request's storage is released: backends
-        may retain its fully-written prompt extent (the paged backend
-        pins it into the radix prefix cache). Base: nothing to retain."""
-
-    def _begin_fault_tick(self) -> bool:
-        """Consult the fault plan for tick-scoped allocator faults; True
-        while an embargo is live (preemptions this tick are charged to
-        the victim's retry budget). Base: no allocator, nothing to do."""
-        return False
-
-    def _end_run(self) -> None:
-        """Post-run hook: clear any tick-scoped fault state so leak
-        checks and later manual ticks see a clean pool."""
-
-    def _decode_tick(self):
-        """One fused model step over the pool; returns pool logits."""
-        raise NotImplementedError
-
-    # ------------------------------------------ chunked-prefill hooks
+    # ------------------------------------------ admission prefill sizing
 
     def _has_local(self) -> bool:
         return any(bt == "local" for bt in self.cfg.block_types())
@@ -390,27 +423,12 @@ class _SchedulerBase:
             if self._has_local() else 0
 
     def _prefill_seq(self, item: _Queued) -> int:
-        """Sequence capacity of the transient admission prefill cache:
-        the prompt itself (not max_seq — the PR 5 sizing fix), floored
-        at the pool's ring window so ring layouts stay identical."""
-        return max(len(item.prompt) + self.n_prefix, self._ring_window(), 1)
-
-    def _begin_prefill(self, item: _Queued, slots: List[int]) -> _Prefill:
-        """Enter the PREFILLING state for an admitted request."""
-        raise NotImplementedError
-
-    def _prefill_step(self, pf: _Prefill) -> Optional[object]:
-        """Advance one prompt chunk. Returns the last-position logits
-        (V,) once the whole prompt is written, else None (also None if
-        the backend had to preempt ``pf`` itself to stay within its
-        page budget — the request is then back in the queue)."""
-        raise NotImplementedError
-
-    def _finish_prefill(self, pf: _Prefill) -> bool:
-        """Finalize storage for a fully prefilled request (install /
-        share pages across the fan-out). False iff the request had to be
-        preempted instead (paged pool dry)."""
-        raise NotImplementedError
+        """Sequence capacity of the one-shot admission's transient
+        prefill cache: the prompt itself (not max_seq), floored at the
+        pool's ring window so ring layouts stay identical, and rounded
+        up to whole pages for the install scatter."""
+        s = max(len(item.prompt) + self.n_prefix, self._ring_window(), 1)
+        return -(-s // self.page_size) * self.page_size
 
     # ------------------------------------------------------------ submit
 
@@ -423,8 +441,8 @@ class _SchedulerBase:
                max_wall_ticks: Optional[int] = None) -> int:
         """Queue one prompt with its own RNG stream; returns request id.
         ``max_new`` overrides ``kcfg.max_new_tokens`` for this request
-        (mixed-length serving — the paged pool sizes its reservation to
-        the request's own need). ``method`` / ``strategy_factory``
+        (mixed-length serving — the pool sizes its reservation to the
+        request's own need). ``method`` / ``strategy_factory``
         override the scheduler-level strategy for this request, so one
         pool can serve mixed kappa/bon/greedy/stbon traffic.
 
@@ -568,11 +586,11 @@ class _SchedulerBase:
         return self._record_result(rid, res)
 
     def _requeue(self, rid: int) -> _Queued:
-        """Non-terminal teardown: free an admitted request's rows (and
-        storage) and hand back its original submission for replay. The
-        paged backend pins the fully-written prompt extent into the
-        prefix cache first, so the replay aliases it back as a hit. The
-        replay decodes from the original submission RNG stream —
+        """Non-terminal teardown: free an admitted request's rows and
+        pages and hand back its original submission for replay. The
+        fully-written prompt extent is pinned into the prefix cache
+        first, so the replay aliases it back as a hit. The replay
+        decodes from the original submission RNG stream —
         token-for-token identical to a never-disturbed run."""
         if rid in self.prefilling:
             pf = self.prefilling.pop(rid)
@@ -710,9 +728,8 @@ class _SchedulerBase:
             self.frontend)
         self.admit_peak_bytes = max(self.admit_peak_bytes,
                                     cache_lib.cache_bytes(cache1))
-        # backends install the batch-1 prefill directly (the paged pool
-        # aliases shared prompt pages; the contiguous pool broadcasts in
-        # the scatter) — no N-row broadcast_batch tile on this path
+        # the batch-1 prefill installs directly, its shared prompt pages
+        # aliased — no N-row broadcast_batch tile on this path
         self._install(slots, item, cache1)
         self._start_request(item, slots, pf_logits)
         return True
@@ -752,16 +769,15 @@ class _SchedulerBase:
 
     def _activate(self, rid: int, rs: strategies.RequestState,
                   slots: List[int]) -> None:
-        """Make ``rid`` active on ``slots``. Fused sampling moves its RNG
+        """Make ``rid`` active on ``slots``, moving its RNG
         stream (advanced past the first tokens on the host) into a
         device stream slot; from here on ``rs.rng`` is stale."""
-        if self._streams is not None:
-            s = self._free_streams.pop()
-            self._stream_of[rid] = s
-            self._streams = _put_stream(self._streams, s,
-                                        strategies.raw_key(rs.rng))
-            self._stream_adv[s] = True
-            self._n_want_lp += rs.strategy.wants_picked_lp
+        s = self._free_streams.pop()
+        self._stream_of[rid] = s
+        self._streams = _put_stream(self._streams, s,
+                                    strategies.raw_key(rs.rng))
+        self._stream_adv[s] = True
+        self._n_want_lp += rs.strategy.wants_picked_lp
         self._set_rows(rid, rs, slots)
 
     def _set_rows(self, rid: int, rs: strategies.RequestState,
@@ -770,10 +786,9 @@ class _SchedulerBase:
         its stream, as admission and compaction leave them."""
         self.active[rid] = (rs, slots)
         self._slots_dev[rid] = jnp.asarray(slots)
-        if self._streams is not None:
-            self._row_stream[slots] = self._stream_of[rid]
-            self._row_branch[slots] = np.arange(len(slots))
-            self._row_greedy[slots] = rs.strategy.greedy
+        self._row_stream[slots] = self._stream_of[rid]
+        self._row_branch[slots] = np.arange(len(slots))
+        self._row_greedy[slots] = rs.strategy.greedy
 
     def _deactivate(self, rid: int):
         """Take ``rid`` out of the active set and free its stream slot
@@ -781,23 +796,11 @@ class _SchedulerBase:
         RNG). Returns its ``(RequestState, slots)``."""
         rs, slots = self.active.pop(rid)
         self._slots_dev.pop(rid, None)
-        s = self._stream_of.pop(rid, None)
-        if s is not None:
-            self._stream_adv[s] = False
-            self._free_streams.append(s)
-            self._n_want_lp -= rs.strategy.wants_picked_lp
+        s = self._stream_of.pop(rid)
+        self._stream_adv[s] = False
+        self._free_streams.append(s)
+        self._n_want_lp -= rs.strategy.wants_picked_lp
         return rs, slots
-
-    def _fuse_candidates(self) -> List[int]:
-        """rids of the PREFILLING requests whose next chunks should ride
-        the tick's fused decode dispatch instead of their own standalone
-        dispatches (backends that support it return all of them in
-        admission order; base: none)."""
-        return []
-
-    def _account_pages_tick(self) -> None:
-        """Page-usage accounting for ticks that skip the decode path
-        (prefill-only); the paged backend overrides."""
 
     def _advance_one_prefill(self, rid: int) -> None:
         """One standalone chunk for ``rid`` (absent = already preempted
@@ -826,18 +829,10 @@ class _SchedulerBase:
             if rid not in fused:
                 self._advance_one_prefill(rid)
 
-    def _post_tick_prefill(self) -> None:
-        """Finalize a fused chunk that completed its prompt this tick
-        (the activated request joins the NEXT decode tick)."""
-
-    def _publish_prefix(self, item: Optional[_Queued], rs, slots) -> None:
-        """Completion hook, called BEFORE the request's storage is
-        released: backends may retain its prefix extent (the paged
-        backend publishes prompt + winner pages into the radix prefix
-        cache). Base: nothing to retain."""
-
     def _release(self, slots: List[int]) -> None:
-        self._release_storage(slots)
+        for s in slots:
+            self.alloc.free_row(s)
+        self._bt_dev = None
         self.row_token[slots] = 0
         self.row_pos[slots] = 0
         self._row_greedy[slots] = True
@@ -847,11 +842,10 @@ class _SchedulerBase:
     def _maybe_pool_controller(self, rs: strategies.RequestState,
                                item: _Queued) -> None:
         """Attach a pooled-controller slot to a kappa request. Pooling
-        needs the fused tick (signals come from the pool logits) and a
-        controller-compatible kcfg; anything else keeps the per-request
-        local controller, which stays correct — just slower."""
-        if not (self.fused_sampling
-                and isinstance(rs.strategy, strategies.KappaStrategy)
+        needs a controller-compatible kcfg; any other keeps the
+        per-request local controller, which stays correct — just
+        slower."""
+        if not (isinstance(rs.strategy, strategies.KappaStrategy)
                 and strategies.controller_key(item.kcfg) == self._ctrl_key):
             return
         if self._kappa_pool is None:
@@ -930,7 +924,7 @@ class _SchedulerBase:
                         for rid in rids:
                             self._advance_one_prefill(rid)
                 if progressed:
-                    # PREFILLING requests hold rows (and, paged, pages) —
+                    # PREFILLING requests hold rows and pages —
                     # account them so utilization metrics stay honest
                     # over chunked-admission-heavy stretches
                     self._occupied_ticks += self.rows - len(self.free)
@@ -961,46 +955,38 @@ class _SchedulerBase:
                 # `bad` — the same path a real numerics blowup would take
                 finite_dev = engine.rows_finite(logits)
 
-            toks = picked = finite = None
-            if self.fused_sampling:
-                # one fused sampling dispatch for the whole pool, which
-                # advances every active stream and derives the row keys
-                # on the device; free rows ride along as masked argmax
-                # (ignored)
-                with tt.span("keys"):
-                    streams = sampler.RowStreams(
-                        self._streams, jnp.asarray(self._row_stream),
-                        jnp.asarray(self._row_branch),
-                        jnp.asarray(self._stream_adv))
-                    gmask = jnp.asarray(self._row_greedy)
-                with tt.span("sample"):
-                    # picked-token log-probs fused into the sampling
-                    # dispatch so BoN-style strategies do zero device
-                    # work per request
-                    toks_dev, lp_dev, self._streams = sampler.sample_rows(
-                        streams, logits, gmask, self.kcfg,
-                        want_picked_lp=self._n_want_lp > 0)
-                self.counters["sampler_dispatches"] += 1
+            # one fused sampling dispatch for the whole pool, which
+            # advances every active stream and derives the row keys on
+            # the device; free rows ride along as masked argmax (ignored)
+            with tt.span("keys"):
+                streams = sampler.RowStreams(
+                    self._streams, jnp.asarray(self._row_stream),
+                    jnp.asarray(self._row_branch),
+                    jnp.asarray(self._stream_adv))
+                gmask = jnp.asarray(self._row_greedy)
+            with tt.span("sample"):
+                # picked-token log-probs fused into the sampling dispatch
+                # so BoN-style strategies do zero device work per request
+                toks_dev, lp_dev, self._streams = sampler.sample_rows(
+                    streams, logits, gmask, self.kcfg,
+                    want_picked_lp=self._n_want_lp > 0)
+            self.counters["sampler_dispatches"] += 1
 
-                # the pooled controller consumes the pool logits and the
-                # just-sampled tokens device-to-device — no host
-                # round-trip
-                with tt.span("control"):
-                    ctrl_dev = self._pooled_kappa_dispatch(logits, toks_dev)
+            # the pooled controller consumes the pool logits and the
+            # just-sampled tokens device-to-device — no host round-trip
+            with tt.span("control"):
+                ctrl_dev = self._pooled_kappa_dispatch(logits, toks_dev)
 
-                # ONE blocking transfer for sampled tokens, picked
-                # log-probs AND all pooled controller outputs (alive/
-                # traj/cutoff of every kappa request), independent of
-                # active-request count
-                with tt.span("sync"):
-                    toks, picked, ctrl_host, finite = jax.device_get(
-                        (toks_dev, lp_dev, ctrl_dev, finite_dev))
-                    self.counters["host_syncs"] += 1
-                    if ctrl_host is not None:
-                        self.counters["controller_syncs"] += 1
-                        self._kappa_pool.publish(ctrl_host)
-            elif finite_dev is not None:
-                finite = jax.device_get(finite_dev)
+            # ONE blocking transfer for sampled tokens, picked log-probs
+            # AND all pooled controller outputs (alive/traj/cutoff of
+            # every kappa request), independent of active-request count
+            with tt.span("sync"):
+                toks, picked, ctrl_host, finite = jax.device_get(
+                    (toks_dev, lp_dev, ctrl_dev, finite_dev))
+                self.counters["host_syncs"] += 1
+                if ctrl_host is not None:
+                    self.counters["controller_syncs"] += 1
+                    self._kappa_pool.publish(ctrl_host)
 
             with tt.span("host"):
                 self._advance_rows(logits, toks, picked, finite)
@@ -1025,19 +1011,16 @@ class _SchedulerBase:
         stamped = list(self.active)
         for rid in list(self.active):
             rs, slots = self.active[rid]
-            if toks is None:
-                dec = rs.sample_and_advance(logits[self._slots_dev[rid]])
+            lp = picked[slots] if (picked is not None
+                                   and rs.strategy.wants_picked_lp) else None
+            # skip the per-request device gather when the strategy won't
+            # read the logits (greedy; BoN once lp is fused; pooled kappa
+            # — its signals come from the pool logits)
+            if rs.strategy.needs_step_logits and lp is None:
+                req_logits = logits[self._slots_dev[rid]]
             else:
-                lp = picked[slots] if (picked is not None
-                                       and rs.strategy.wants_picked_lp) else None
-                # skip the per-request device gather when the strategy
-                # won't read the logits (greedy; BoN once lp is fused;
-                # pooled kappa — its signals come from the pool logits)
-                if rs.strategy.needs_step_logits and lp is None:
-                    req_logits = logits[self._slots_dev[rid]]
-                else:
-                    req_logits = None
-                dec = rs.advance(req_logits, toks[slots], picked_lp=lp)
+                req_logits = None
+            dec = rs.advance(req_logits, toks[slots], picked_lp=lp)
             if dec.keep is not None:
                 kept = [slots[i] for i in dec.keep]
                 self._release(sorted(set(slots) - set(kept)))
@@ -1123,10 +1106,10 @@ class _SchedulerBase:
     def snapshot(self, reset_window: bool = False) -> Dict[str, float]:
         """Windowed latency/throughput counters accumulated since the
         last ``snapshot(reset_window=True)`` (or construction).  The SLO
-        controller and the open-loop arrival sweeps read per-window
-        percentiles here instead of the run-lifetime aggregates in
-        ``latency_stats()``/``throughput()``, so a transient overload is
-        visible the window it happens rather than diluted over the run."""
+        controller reads per-window percentiles here instead of the
+        run-lifetime aggregates in ``latency_stats()``/``throughput()``,
+        so a transient overload is visible the window it happens rather
+        than diluted over the run."""
         now = self.clock()
         win_s = max(now - self._win_t0, 1e-9)
         ttft, itl = self._win_ttft, self._win_itl
@@ -1162,11 +1145,17 @@ class _SchedulerBase:
     # ----------------------------------------------------------- metrics
 
     def request_bytes(self) -> Dict[int, int]:
-        """Per-request paged-view bytes currently referenced in the pool."""
-        return cache_lib.per_request_bytes(
-            self.cfg, {rid: (len(slots), rs.pos)
-                       for rid, (rs, slots) in self.active.items()},
-            self.max_seq)
+        """Per-request bytes from allocator truth: pages the request's
+        rows reference — shared prompt pages charged ONCE — times the
+        per-page byte cost, plus the analytic per-row cost of the
+        non-paged leaf families (ring / recurrent / rwkv6 / cross-KV)."""
+        pb = cache_lib.page_bytes(self.cfg, self.page_size)
+        out = {}
+        for rid, (rs, slots) in self.active.items():
+            pages = {int(p) for s in slots for p in self.alloc.row_pages(s)}
+            out[rid] = len(pages) * pb + cache_lib.used_cache_bytes(
+                self.cfg, len(slots), rs.pos, self.max_seq, skip_global=True)
+        return out
 
     def throughput(self) -> Dict[str, float]:
         """Aggregate serving metrics over a completed ``run()``."""
@@ -1183,6 +1172,9 @@ class _SchedulerBase:
             "requests_per_s": len(self.results) / elapsed,
             "row_utilization": (self._occupied_ticks
                                 / max(self.ticks * self.rows, 1)),
+            "page_utilization": (self._page_ticks
+                                 / max(self.ticks * self.num_pages, 1)),
+            "page_peak": self._page_peak,
         }
         # host seconds per tick phase (serving/spans.py): "step" and
         # "sample"/"control" time the enqueue of the device programs,
@@ -1192,20 +1184,25 @@ class _SchedulerBase:
         # regression the breakdown exists to make visible)
         for k, v in self.tick_time.items():
             out[f"time_{k}_s"] = v
+        # the counters include the prefix cache's (zeros when it is off)
         out.update(self.counters)
         status_counts: Dict[str, int] = {}
         for r in self.results.values():
             status_counts[r.status] = status_counts.get(r.status, 0) + 1
         out["status_counts"] = status_counts
         out["admit_peak_bytes"] = self.admit_peak_bytes
+        looked = (self.counters["prefix_hits"]
+                  + self.counters["prefix_misses"])
+        out["prefix_hit_rate"] = self.counters["prefix_hits"] / max(looked, 1)
+        out["prefix_pinned_pages"] = (self.pcache.pinned_count
+                                      if self.pcache is not None else 0)
         out.update(self.latency_stats())
         return out
 
     def latency_stats(self) -> Dict[str, float]:
         """TTFT / inter-token-latency percentiles over every request
         served so far (per-request stamps stay in ``token_times`` for
-        finer-grained windows — the interleaving benchmark reads them
-        directly)."""
+        finer-grained windows)."""
         ttft = np.asarray(sorted(self.ttft.values()) or [0.0])
         itl = np.asarray([d for ts in self.token_times.values()
                           for d in np.diff(ts)] or [0.0])
@@ -1216,212 +1213,6 @@ class _SchedulerBase:
             "itl_p99_s": float(np.percentile(itl, 99)),
             "itl_max_s": float(itl.max()),
         }
-
-
-class ContinuousBatchingScheduler(_SchedulerBase):
-    """Contiguous-pool scheduler: a fixed ``(rows, max_seq)`` device
-    cache allocated once, FIFO admission counted in rows (no head-of-line
-    bypass, keeping completion order fair). Every admitted row reserves
-    ``max_seq`` KV slots for its whole life — the reservation slack the
-    paged backend removes.
-
-    Parameters
-    ----------
-    rows : total branch slots in the device pool. Must be >= the fan-out
-        of a single request (``strategy.rows(kcfg)``).
-    max_seq : shared sequence capacity of every pool row. Each admitted
-        prompt must satisfy ``len(prompt) + n_prefix + max_new <= max_seq``.
-    method : one of "greedy" | "bon" | "stbon" | "kappa"; or pass
-        ``strategy_factory`` for custom construction (e.g. ST-BoN with a
-        non-default buffer window).
-    """
-
-    def __init__(self, params, cfg: ModelConfig, kcfg: KappaConfig, *,
-                 rows: int, max_seq: int, method: str = "kappa",
-                 eos_id: int, bos_id: int = 0, frontend=None,
-                 strategy_factory=None, fused_sampling: bool = True,
-                 prefill_chunk: Optional[int] = None,
-                 faults: Optional[faults_lib.FaultPlan] = None,
-                 max_retries: int = 3, retry_backoff: int = 2,
-                 max_queue: Optional[int] = None,
-                 clock: Optional[Callable[[], float]] = None,
-                 event_sink: Optional[Callable[[TokenEvent], None]] = None):
-        super().__init__(params, cfg, kcfg, rows=rows, max_seq=max_seq,
-                         method=method, eos_id=eos_id, bos_id=bos_id,
-                         frontend=frontend, strategy_factory=strategy_factory,
-                         fused_sampling=fused_sampling,
-                         prefill_chunk=prefill_chunk, faults=faults,
-                         max_retries=max_retries, retry_backoff=retry_backoff,
-                         max_queue=max_queue, clock=clock,
-                         event_sink=event_sink)
-        self.pool = init_cache(cfg, rows, max_seq)
-
-    def _admissible(self, item: _Queued) -> bool:
-        return len(self.free) >= item.fan_out
-
-    def _select_admit(self) -> Optional[int]:
-        # FIFO among READY items: admit the first one not backing off,
-        # or nothing — head-or-nothing, so ready requests keep FIFO
-        # completion order while a retry waits out its backoff
-        for i, item in enumerate(self.queue):
-            if item.not_before > self.ticks:
-                continue
-            return i if self._admissible(item) else None
-        return None
-
-    def _install(self, slots, item, sub1) -> None:
-        # the batch-1 prefill broadcasts across the n slots inside the
-        # scatter itself (prefix-extent: the sub-cache is prompt-sized,
-        # row tails past the prompt are never read) — no separate N-row
-        # tile materialized
-        self.pool = _scatter(self.pool, jnp.asarray(slots), sub1)
-
-    # ------------------------------------------------- chunked prefill
-
-    def _begin_prefill(self, item, slots) -> _Prefill:
-        cache1 = init_cache(self.cfg, 1, self._prefill_seq(item))
-        self.admit_peak_bytes = max(self.admit_peak_bytes,
-                                    cache_lib.cache_bytes(cache1))
-        return _Prefill(item=item, slots=slots, cache1=cache1)
-
-    def _prefill_step(self, pf: _Prefill):
-        plen = len(pf.item.prompt)
-        c = min(self.prefill_chunk, plen - pf.filled)
-        piece = pf.item.prompt[pf.filled:pf.filled + c]
-        logits, pf.cache1, _ = engine._prefill_chunk_contig(
-            self.params, self.cfg, jnp.asarray(piece)[None],
-            jnp.full((1,), pf.filled, jnp.int32), pf.filled, pf.cache1)
-        pf.filled += c
-        return logits[0] if pf.filled >= plen else None
-
-    def _finish_prefill(self, pf: _Prefill) -> bool:
-        self._install(pf.slots, pf.item, pf.cache1)
-        pf.cache1 = None
-        return True
-
-    def _decode_tick(self):
-        with self.tick_time.span("step"):
-            engine.check_step_fault(self.faults, self.ticks)
-            logits, self.pool = engine._model_step(
-                self.params, self.cfg, jnp.asarray(self.row_token),
-                jnp.asarray(self.row_pos), self.pool)
-        return logits
-
-
-class PagedScheduler(_SchedulerBase):
-    """Paged-pool scheduler (DESIGN.md §5).
-
-    Global-attention KV lives in a shared page pool; each row addresses
-    it through a ``(max_pages,)`` block table. Fan-out branches *share*
-    the fully-written prompt pages copy-on-write: admission allocates
-    them once, aliases them into all N branch tables, and gives each
-    branch a private copy of the partially-written boundary page (where
-    divergent decode writes land) plus one decode page — so admission
-    costs ``prompt_pages + N × (1 + boundary)`` pages instead of
-    ``N × ceil(need / page_size)``. Decode pages are acquired *lazily*,
-    one page per row as its position crosses a page boundary; when the
-    free list runs dry the scheduler preempts the youngest-admitted
-    request (pages freed, request requeued and replayed from its
-    original RNG — token-for-token identical to an un-preempted run)
-    instead of deadlocking. Pruning a branch drops its page references
-    immediately; a page returns to the free heap when its last
-    reference goes.
-
-    Queued requests are admitted shortest-job-first among those whose
-    rows *and* initial pages fit (FIFO tie-break on equal need), with
-    bounded bypass: once the queue head has been bypassed
-    ``max_bypass`` times, it is admitted next or nothing is — a steady
-    stream of short submissions can no longer starve a long request.
-
-    Parameters
-    ----------
-    rows : row slots (block tables / position vector entries).
-    max_seq : upper bound on any request's ``prompt + n_prefix + max_new``
-        (rounded up to a page multiple internally).
-    page_size : token slots per page. On TPU this should match the
-        flash-decode kernel's S-tile so one page = one VMEM tile DMA.
-    num_pages : allocatable pages in the pool — the real memory knob.
-        Defaults to ``rows * max_seq / page_size`` (no page pressure);
-        set lower to serve more rows than a contiguous pool of the same
-        byte budget could.
-    page_budget_bytes : alternative memory knob — an HBM byte budget
-        for the global-layer page pool, converted to ``num_pages`` via
-        allocator-truth :func:`cache.page_bytes` (so an int8
-        ``kv_cache_dtype`` yields ≈2× the pages of fp32/bf16 under the
-        same budget). Mutually exclusive with ``num_pages``.
-    max_bypass : SJF aging bound (see above).
-    prefix_cache : enable the cross-request radix prefix cache
-        (DESIGN.md §7). Completed/preempted requests publish their
-        fully-written prompt pages (and the winner's generated prefix)
-        into a radix tree that pins them in the allocator; later
-        admissions alias every matched page and chunk-prefill only the
-        uncached tail. Requires chunked admission (``prefill_chunk``)
-        and an all-global layer pattern — anything else silently keeps
-        the cache off (aux ring/recurrent state cannot be recovered
-        from pages, and only the chunked path can resume a prefill at a
-        nonzero offset).
-    """
-
-    def __init__(self, params, cfg: ModelConfig, kcfg: KappaConfig, *,
-                 rows: int, max_seq: int, page_size: int = 64,
-                 num_pages: Optional[int] = None, method: str = "kappa",
-                 eos_id: int, bos_id: int = 0, frontend=None,
-                 strategy_factory=None, fused_sampling: bool = True,
-                 max_bypass: int = 4, prefill_chunk: Optional[int] = None,
-                 prefix_cache: bool = False,
-                 faults: Optional[faults_lib.FaultPlan] = None,
-                 max_retries: int = 3, retry_backoff: int = 2,
-                 max_queue: Optional[int] = None,
-                 clock: Optional[Callable[[], float]] = None,
-                 event_sink: Optional[Callable[[TokenEvent], None]] = None,
-                 page_budget_bytes: Optional[int] = None):
-        max_seq = -(-max_seq // page_size) * page_size
-        if page_budget_bytes is not None:
-            if num_pages is not None:
-                raise ValueError("pass num_pages or page_budget_bytes, "
-                                 "not both")
-            num_pages = page_budget_bytes \
-                // cache_lib.page_bytes(cfg, page_size)
-            if num_pages < 1:
-                raise ValueError(
-                    f"page_budget_bytes={page_budget_bytes} below one "
-                    f"page ({cache_lib.page_bytes(cfg, page_size)}B)")
-        super().__init__(params, cfg, kcfg, rows=rows, max_seq=max_seq,
-                         method=method, eos_id=eos_id, bos_id=bos_id,
-                         frontend=frontend, strategy_factory=strategy_factory,
-                         fused_sampling=fused_sampling,
-                         prefill_chunk=prefill_chunk, faults=faults,
-                         max_retries=max_retries, retry_backoff=retry_backoff,
-                         max_queue=max_queue, clock=clock,
-                         event_sink=event_sink)
-        self.page_size = page_size
-        self.max_pages = max_seq // page_size
-        self.num_pages = num_pages if num_pages is not None \
-            else rows * self.max_pages
-        self.max_bypass = max_bypass
-        self.alloc = cache_lib.PageAllocator(self.num_pages, page_size,
-                                             rows, self.max_pages,
-                                             fault_plan=self.faults)
-        self.pool = init_paged_cache(cfg, rows, self.num_pages, page_size,
-                                     max_seq)
-        # radix prefix cache: only sound when every layer's KV is page-
-        # resident (all-global) and admission can resume a prefill at
-        # the cached extent (chunked)
-        self.pcache: Optional[cache_lib.RadixPrefixCache] = None
-        if prefix_cache and self._chunked_ok \
-                and all(bt == "global" for bt in cfg.block_types()):
-            self.pcache = cache_lib.RadixPrefixCache(self.alloc, page_size)
-        self.counters.update({
-            "prefix_hits": 0, "prefix_misses": 0,
-            "prefix_tokens_saved": 0, "prefix_evictions": 0,
-            "fused_chunks": 0,
-            # pages the decode kernel walks (each row's up to its
-            # position) against its block-table slots (rows x max_pages)
-            "kv_pages_live": 0, "kv_pages_table": 0,
-        })
-        self._page_ticks = 0                 # Σ pages in use over ticks
-        self._page_peak = 0                  # max pages in use at any tick
-        self._bt_dev = None                  # device block tables (cached)
 
     # --------------------------------------------------- page accounting
 
@@ -1521,6 +1312,10 @@ class PagedScheduler(_SchedulerBase):
         return best
 
     def _install(self, slots, item, sub1) -> None:
+        """Install a one-shot admission's batch-1 prefill cache ``sub1``
+        into the row slots: shared prompt pages once, aliased into every
+        branch table, plus each branch's private boundary copy and first
+        decode page."""
         full = self._shared_pages(item)
         boundary = self._boundary(item)
         n_priv = self._initial_priv(item)
@@ -1542,20 +1337,20 @@ class PagedScheduler(_SchedulerBase):
             jnp.asarray(np.asarray(src, np.int32)),
             jnp.asarray(np.asarray(phys, np.int32)), sub1, self.page_size)
 
-    def _release_storage(self, slots) -> None:
-        for s in slots:
-            self.alloc.free_row(s)
-        self._bt_dev = None
-
     # ------------------------------------------- lazy growth / preemption
 
     def _begin_fault_tick(self) -> bool:
+        """Consult the fault plan for tick-scoped allocator faults; True
+        while an embargo is live (preemptions this tick are charged to
+        the victim's retry budget)."""
         hb = self.alloc.begin_tick(self.ticks)
         if hb:
             self.counters["faults_injected"] += 1
         return hb > 0
 
     def _end_run(self) -> None:
+        """Post-run: clear any tick-scoped fault state so leak checks
+        and later manual ticks see a clean pool."""
         self.alloc.holdback = 0
 
     def _publish_prompt_pages(self, prompt: np.ndarray, slot: int,
@@ -1644,12 +1439,6 @@ class PagedScheduler(_SchedulerBase):
     # be shared copy-on-write anyway). Pages are acquired lazily chunk by
     # chunk; the heap running dry preempts the youngest-started request,
     # possibly this prefill itself.
-
-    def _prefill_seq(self, item: _Queued) -> int:
-        # the one-shot fallback's install scatter reshapes the transient
-        # cache into whole pages
-        s = super()._prefill_seq(item)
-        return -(-s // self.page_size) * self.page_size
 
     def _begin_prefill(self, item, slots) -> _Prefill:
         aux = init_cache(self.cfg, 1, max(self._ring_window(), 1))
@@ -1774,6 +1563,9 @@ class PagedScheduler(_SchedulerBase):
         return True
 
     def _fuse_candidates(self) -> List[int]:
+        """rids of the PREFILLING requests whose next chunks ride the
+        tick's fused decode dispatch instead of their own standalone
+        dispatches, in admission order (none when no row decodes)."""
         # EVERY prefilling request rides the decode dispatch: one tick =
         # one fused device program = decode + all concurrent prompt
         # chunks (PR 5 fused only the oldest; with prefix-cache hits
@@ -1784,6 +1576,7 @@ class PagedScheduler(_SchedulerBase):
         return sorted(self.prefilling, key=lambda r: self._admit_seq[r])
 
     def _account_pages_tick(self) -> None:
+        """Pages in use this tick, summed over ticks and maxed."""
         self._page_ticks += self.alloc.used_count
         self._page_peak = max(self._page_peak, self.alloc.used_count)
 
@@ -1829,8 +1622,8 @@ class PagedScheduler(_SchedulerBase):
         engine.check_step_fault(self.faults, self.ticks)
         # grow every fused chunk's pages FIRST — growth can evict or
         # preempt, which must settle before write pages are certified
-        # below (growth runs in admission order, matching the standalone
-        # dispatch order a non-fusing backend would use)
+        # below (growth runs in admission order, matching the order of
+        # standalone chunk dispatches)
         fused = []                           # (rid, pf, chunk_len)
         for rid in self._fused_rids:
             pf = self.prefilling.get(rid)
@@ -1862,6 +1655,8 @@ class PagedScheduler(_SchedulerBase):
                 for rid, pf, c in fused], wp
 
     def _post_tick_prefill(self) -> None:
+        """Finalize the fused chunks that completed their prompts this
+        tick (the activated requests join the NEXT decode tick)."""
         rids, self._fused_rids = self._fused_rids, []
         out, self._fused_chunk_out = self._fused_chunk_out, None
         if not rids or out is None:
@@ -1898,9 +1693,10 @@ class PagedScheduler(_SchedulerBase):
                 return None
         return idx
 
-    def publish_generated_prefix(self, item: _Queued, rs, slots) -> None:
-        """Completion-side publication (the Path-Consistency scenario):
-        pin the winner's full fully-written extent — prompt AND
+    def _publish_prefix(self, item: _Queued, rs, slots) -> None:
+        """Completion-side publication, called BEFORE the request's
+        pages are released (the Path-Consistency scenario): pin the
+        winner's full fully-written extent — prompt AND
         surviving generated prefix — into the radix tree, so a later
         sampling of the same problem that extends this prefix aliases
         the winner's pages instead of re-prefilling them. The fed
@@ -1908,7 +1704,7 @@ class PagedScheduler(_SchedulerBase):
         sampled but never fed) padded with the forced-EOS feeds of
         post-done ticks; when that reconstruction isn't certain
         (:meth:`_winner_extent`) only the prompt pages are published."""
-        if self.pcache is None or item is None or not slots:
+        if self.pcache is None or not slots:
             return
         prompt = item.prompt    # already a host ndarray (submit())
         idx = self._winner_extent(rs)
@@ -1926,36 +1722,3 @@ class PagedScheduler(_SchedulerBase):
         if k:
             pages = [int(p) for p in self.alloc.block[slots[idx], :k]]
             self.pcache.publish(seq[:k * self.page_size], pages)
-
-    def _publish_prefix(self, item, rs, slots) -> None:
-        self.publish_generated_prefix(item, rs, slots)
-
-    # ----------------------------------------------------------- metrics
-
-    def request_bytes(self) -> Dict[int, int]:
-        """Per-request bytes from allocator truth: pages the request's
-        rows reference — shared prompt pages charged ONCE — times the
-        per-page byte cost, plus the analytic per-row cost of the
-        non-paged leaf families (ring / recurrent / rwkv6 / cross-KV)."""
-        pb = cache_lib.page_bytes(self.cfg, self.page_size)
-        out = {}
-        for rid, (rs, slots) in self.active.items():
-            pages = {int(p) for s in slots for p in self.alloc.row_pages(s)}
-            out[rid] = len(pages) * pb + cache_lib.used_cache_bytes(
-                self.cfg, len(slots), rs.pos, self.max_seq, skip_global=True)
-        return out
-
-    def throughput(self) -> Dict[str, float]:
-        out = super().throughput()
-        out["page_utilization"] = (self._page_ticks
-                                   / max(self.ticks * self.num_pages, 1))
-        out["page_peak"] = self._page_peak
-        # prefix-cache observability (zeros when the cache is off): the
-        # prefix_hits/misses/tokens_saved/evictions counters ride along
-        # via the shared counters dict above
-        looked = (self.counters["prefix_hits"]
-                  + self.counters["prefix_misses"])
-        out["prefix_hit_rate"] = self.counters["prefix_hits"] / max(looked, 1)
-        out["prefix_pinned_pages"] = (self.pcache.pinned_count
-                                      if self.pcache is not None else 0)
-        return out

@@ -33,7 +33,7 @@ trust the percentile) escalates one level; a *healthy* window
 (``itl_p99 <= recover_frac * target``, or too few samples to judge —
 an idle/draining pool must not stay wedged shut) de-escalates one
 level; anything in between holds (hysteresis). Every evaluation is
-appended to ``history`` so benchmarks can plot the controller's path.
+appended to ``history``, the record of the controller's path.
 
 Windows are tick-counted (``window_ticks``), not wall-timed: the
 driving loop calls ``on_tick()`` after every scheduler tick and the

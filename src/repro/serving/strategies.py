@@ -423,10 +423,10 @@ class KappaStrategy(DecodeStrategy):
 
     Two controller backends behind the same host-side decisions:
 
-      * **local** (single-request engine loop, or ``fused_sampling=False``
-        schedulers): this strategy owns a jitted per-request
-        ``kappa_step`` — one dispatch and one blocking ``np.asarray``
-        sync per step.
+      * **local** (the single-request engine loop, or a scheduler
+        request whose kcfg does not match the pool's controller key):
+        this strategy owns a jitted per-request ``kappa_step`` — one
+        dispatch and one blocking ``np.asarray`` sync per step.
       * **pooled** (the batched scheduler path): the scheduler attaches a
         :class:`PooledKappaController` slot; the controller math runs in
         the scheduler's single fused tick dispatch and this strategy only
@@ -601,10 +601,10 @@ class RequestState:
     to its own row storage (gather for a dedicated cache, slot freeing
     for the shared pool).
 
-    A scheduler with fused sampling holds the stream itself, in a device
-    table, from activation (after :meth:`first_tokens`) until the
-    request leaves the pool; ``rng`` is stale over that time and nothing
-    reads it (a replay starts a new state from the submission RNG)."""
+    The scheduler holds the stream itself, in a device table, from
+    activation (after :meth:`first_tokens`) until the request leaves the
+    pool; ``rng`` is stale over that time and nothing reads it (a replay
+    starts a new state from the submission RNG)."""
 
     def __init__(self, strategy: DecodeStrategy, params, cfg: ModelConfig,
                  kcfg: KappaConfig, prompt_len: int, rng, *, eos_id: int,

@@ -51,13 +51,13 @@ def _sync_budget_guard(request, monkeypatch):
         return
     from repro.serving import scheduler as sched_mod
     created = []
-    orig_init = sched_mod._SchedulerBase.__init__
+    orig_init = sched_mod.PagedScheduler.__init__
 
     def _tracking_init(self, *args, **kwargs):
         orig_init(self, *args, **kwargs)
         created.append(self)
 
-    monkeypatch.setattr(sched_mod._SchedulerBase, "__init__",
+    monkeypatch.setattr(sched_mod.PagedScheduler, "__init__",
                         _tracking_init)
     yield
     for sched in created:

@@ -1,8 +1,8 @@
 """Chunked prefill interleaved with decode (DESIGN.md §6): bitwise
 equality of the final chunk's logits with one-shot prefill, chunk/page
-boundary edge cases, scheduler equivalence with chunked admission on
-both backends, mid-PREFILLING preemption replay, decode-stall bounds,
-and the prompt-sized admission-cache regression."""
+boundary edge cases, scheduler equivalence with chunked admission with
+and without the prefix cache, mid-PREFILLING preemption replay,
+decode-stall bounds, and the prompt-sized admission-cache regression."""
 import dataclasses
 
 import jax
@@ -18,7 +18,7 @@ from repro.models import (init_cache, init_paged_cache, init_params,
 from repro.serving import cache as cache_lib
 from repro.serving import engine
 from repro.serving.cache import PageAllocator
-from repro.serving.scheduler import ContinuousBatchingScheduler, PagedScheduler
+from repro.serving.scheduler import PagedScheduler
 
 
 @pytest.fixture(scope="module")
@@ -127,31 +127,26 @@ def _check_equal(seq, res, rids):
         assert s.steps == c.steps
 
 
-def test_chunked_contiguous_scheduler_matches_sequential(setup):
-    cfg, params, kcfg, prompts, max_seq = setup
-    seq = _sequential(setup, "kappa")
-    sched = ContinuousBatchingScheduler(
-        params, cfg, kcfg, rows=6, max_seq=max_seq, method="kappa",
-        eos_id=tok.EOS, bos_id=tok.BOS, prefill_chunk=3)
-    rids = [sched.submit(p, jax.random.PRNGKey(i))
-            for i, p in enumerate(prompts)]
-    _check_equal(seq, sched.run(), rids)
-    assert sorted(sched.free) == list(range(6))
-    assert not sched.prefilling
-
-
-def test_chunked_paged_scheduler_matches_sequential(setup):
+@pytest.mark.parametrize("prefix_cache", [False, True],
+                         ids=["paged", "paged+prefix"])
+def test_chunked_paged_scheduler_matches_sequential(setup, prefix_cache):
     cfg, params, kcfg, prompts, max_seq = setup
     seq = _sequential(setup, "kappa")
     sched = PagedScheduler(
         params, cfg, kcfg, rows=6, max_seq=max_seq, page_size=8,
         num_pages=24, method="kappa", eos_id=tok.EOS, bos_id=tok.BOS,
-        prefill_chunk=3)
+        prefill_chunk=3, prefix_cache=prefix_cache)
     rids = [sched.submit(p, jax.random.PRNGKey(i))
             for i, p in enumerate(prompts)]
     _check_equal(seq, sched.run(), rids)
+    assert (sched.pcache is not None) == prefix_cache
+    if prefix_cache:
+        assert sched.pcache.pinned_count > 0   # completions published
+        sched.pcache.drop()
     assert sched.alloc.free_count == 24        # zero leaked pages
+    assert int(sched.alloc.pinned.sum()) == 0  # zero leaked pins
     assert sorted(sched.free) == list(range(6))
+    assert not sched.prefilling
 
 
 def test_chunked_mixed_strategies_match_sequential(setup):
@@ -312,9 +307,9 @@ def test_scheduler_latency_stats(setup):
     """TTFT / ITL accounting: every served request has a TTFT and a
     token timestamp per decode tick it participated in."""
     cfg, params, kcfg, prompts, max_seq = setup
-    sched = ContinuousBatchingScheduler(
-        params, cfg, kcfg, rows=6, max_seq=max_seq, method="kappa",
-        eos_id=tok.EOS, bos_id=tok.BOS, prefill_chunk=4)
+    sched = PagedScheduler(
+        params, cfg, kcfg, rows=6, max_seq=max_seq, page_size=8,
+        method="kappa", eos_id=tok.EOS, bos_id=tok.BOS, prefill_chunk=4)
     rids = [sched.submit(p, jax.random.PRNGKey(i))
             for i, p in enumerate(prompts)]
     res = sched.run()
@@ -331,28 +326,28 @@ def test_scheduler_latency_stats(setup):
 # ------------------------------------------- admission-cache sizing fix
 
 def test_admission_prefill_cache_sized_to_prompt(setup):
-    """Regression (PR 5 satellite): the transient admission prefill
-    cache is sized to the PROMPT, not max_seq — per-admission peak bytes
-    shrink accordingly, and the chunked paged path's aux state is
+    """Regression: the one-shot admission's transient prefill cache is
+    sized to the PROMPT (in whole pages), not max_seq — per-admission
+    peak bytes shrink accordingly, and the chunked path's aux state is
     smaller still (global KV goes straight to pages)."""
     cfg, params, kcfg, prompts, max_seq = setup
     big_seq = 4 * max_seq                     # roomy pool, short prompts
     old_bytes = cache_lib.cache_bytes(init_cache(cfg, 1, big_seq))
 
-    sched = ContinuousBatchingScheduler(
-        params, cfg, kcfg, rows=4, max_seq=big_seq, method="kappa",
-        eos_id=tok.EOS, bos_id=tok.BOS)
+    sched = PagedScheduler(params, cfg, kcfg, rows=4, max_seq=big_seq,
+                           page_size=8, num_pages=64, method="kappa",
+                           eos_id=tok.EOS, bos_id=tok.BOS)
     rid = sched.submit(prompts[0], jax.random.PRNGKey(0), max_new=8)
     sched.run()
-    prompt_bytes = cache_lib.cache_bytes(
-        init_cache(cfg, 1, len(prompts[0])))
+    assert len(prompts[0]) <= 8               # the prompt fits one page
+    prompt_bytes = cache_lib.cache_bytes(init_cache(cfg, 1, 8))
     assert sched.admit_peak_bytes == prompt_bytes
     assert sched.admit_peak_bytes * 4 <= old_bytes
 
-    paged = PagedScheduler(params, cfg, kcfg, rows=4, max_seq=big_seq,
-                           page_size=8, num_pages=64, method="kappa",
-                           eos_id=tok.EOS, bos_id=tok.BOS, prefill_chunk=4)
-    rid = paged.submit(prompts[0], jax.random.PRNGKey(0), max_new=8)
-    paged.run()
-    # chunked paged admissions carry only the batch-1 per-row aux state
-    assert paged.admit_peak_bytes < prompt_bytes
+    chunked = PagedScheduler(params, cfg, kcfg, rows=4, max_seq=big_seq,
+                             page_size=8, num_pages=64, method="kappa",
+                             eos_id=tok.EOS, bos_id=tok.BOS, prefill_chunk=4)
+    rid = chunked.submit(prompts[0], jax.random.PRNGKey(0), max_new=8)
+    chunked.run()
+    # chunked admissions carry only the batch-1 per-row aux state
+    assert chunked.admit_peak_bytes < prompt_bytes

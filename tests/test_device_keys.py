@@ -127,8 +127,8 @@ def test_device_keys_match_host_streams(setup, monkeypatch, typed):
 
 def test_stream_table_needs_partitionable_threefry(setup):
     """With ``jax_threefry_partitionable`` off a key of ``split(k, n)``
-    depends on ``n``, so the fused scheduler refuses to build its
-    stream table; the host-keyed path needs no such flag."""
+    depends on ``n``, so the scheduler refuses to build its stream
+    table."""
     cfg, params, kcfg, _ = setup
     was = jax.config.jax_threefry_partitionable
     jax.config.update("jax_threefry_partitionable", False)
@@ -136,8 +136,6 @@ def test_stream_table_needs_partitionable_threefry(setup):
         with pytest.raises(RuntimeError, match="jax_threefry_partitionable"):
             PagedScheduler(params, cfg, kcfg, rows=8, max_seq=32,
                            page_size=4, eos_id=tok.EOS, bos_id=tok.BOS)
-        PagedScheduler(params, cfg, kcfg, rows=8, max_seq=32, page_size=4,
-                       eos_id=tok.EOS, bos_id=tok.BOS, fused_sampling=False)
     finally:
         jax.config.update("jax_threefry_partitionable", was)
 

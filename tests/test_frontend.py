@@ -1,6 +1,6 @@
 """Streaming front-end semantics (DESIGN.md §9): per-rid event order
 with exactly one terminal event, token-for-token equality between
-streamed and batch ``run()`` serving across all three backends,
+streamed and batch ``run()`` serving across three pool configurations,
 mid-stream cancellation, mixed-strategy concurrent streams vs the
 sequential engine, the thread fallback backend, and zero-leak
 shutdown. No pytest-asyncio: each async scenario runs under
@@ -19,13 +19,13 @@ from repro.data import tokenizer as tok
 from repro.models import init_params
 from repro.serving import engine
 from repro.serving.frontend import ServingFrontend
-from repro.serving.scheduler import (ContinuousBatchingScheduler,
-                                     PagedScheduler)
+from repro.serving.scheduler import PagedScheduler
 
 MAX_SEQ = 32
 PAGE_SIZE = 4
 ROWS = 8
-BACKENDS = ["contig", "paged", "paged+prefix"]
+# one-shot admission; chunked admission; chunked with the prefix cache
+BACKENDS = ["paged-oneshot", "paged", "paged+prefix"]
 
 
 @pytest.fixture(scope="module")
@@ -47,10 +47,9 @@ def setup():
 def _mk(setup, backend, **kw):
     cfg, params, kcfg, _ = setup
     base = dict(rows=ROWS, max_seq=MAX_SEQ, method="kappa",
-                eos_id=tok.EOS, bos_id=tok.BOS, prefill_chunk=4)
+                eos_id=tok.EOS, bos_id=tok.BOS,
+                prefill_chunk=None if backend == "paged-oneshot" else 4)
     base.update(kw)
-    if backend == "contig":
-        return ContinuousBatchingScheduler(params, cfg, kcfg, **base)
     return PagedScheduler(params, cfg, kcfg, page_size=PAGE_SIZE,
                           num_pages=ROWS * MAX_SEQ // PAGE_SIZE,
                           prefix_cache=backend.endswith("prefix"), **base)
@@ -59,11 +58,10 @@ def _mk(setup, backend, **kw):
 def _assert_no_leaks(sched):
     assert sorted(sched.free) == list(range(sched.rows))
     assert not sched.active and not sched.prefilling and not sched.queue
-    if getattr(sched, "pcache", None) is not None:
+    if sched.pcache is not None:
         sched.pcache.drop()
-    if hasattr(sched, "alloc"):
-        assert sched.alloc.free_count == sched.num_pages, "leaked pages"
-        assert int(sched.alloc.pinned.sum()) == 0, "leaked pins"
+    assert sched.alloc.free_count == sched.num_pages, "leaked pages"
+    assert int(sched.alloc.pinned.sum()) == 0, "leaked pins"
 
 
 async def _consume(fe, prompt, i, **kw):
@@ -104,8 +102,8 @@ def test_event_order_and_single_terminal(setup):
 @pytest.mark.parametrize("backend", BACKENDS)
 def test_stream_matches_batch_run(setup, backend):
     """The acceptance property: streamed requests are token-for-token
-    equal to batch ``run()`` on the same seeds, for contiguous, paged,
-    and paged+prefix-cache backends."""
+    equal to batch ``run()`` on the same seeds, with one-shot and chunked
+    admission and with the prefix cache."""
     _, _, _, prompts = setup
     batch_sched = _mk(setup, backend)
     rids = [batch_sched.submit(p, jax.random.PRNGKey(i))
@@ -189,7 +187,7 @@ def test_mixed_pool_concurrent_streams_match_sequential(setup):
 
 def test_thread_backend_stream_and_result(setup):
     _, _, _, prompts = setup
-    sched = _mk(setup, "contig")
+    sched = _mk(setup, "paged-oneshot")
     with ServingFrontend(sched) as fe:
         r0 = fe.submit_nowait(prompts[0], jax.random.PRNGKey(0),
                               method="greedy")
@@ -280,9 +278,9 @@ def test_failed_tick_fails_frontend_serve(monkeypatch):
     def serve():
         try:
             serve_eval("deepseek-r1-distill-qwen-1.5b", "kappa", n=2,
-                       problems=2, max_new=8, d_model=64, paged=True,
-                       page_size=8, prefill_chunk=4, frontend_serve=True,
-                       stream=True, verbose=False)
+                       problems=2, max_new=8, d_model=64, page_size=8,
+                       prefill_chunk=4, frontend_serve=True, stream=True,
+                       verbose=False)
         except Exception as e:
             raised.append(e)
 
@@ -326,9 +324,8 @@ def test_full_width_serves_config_as_published(monkeypatch):
         get_config("deepseek-r1-distill-qwen-1.5b"), num_layers=3,
         d_model=96, num_heads=6, num_kv_heads=2, d_ff=128, vocab_size=512)
     monkeypatch.setattr(serve_mod, "get_config", lambda arch: small)
-    kw = dict(n=2, problems=2, max_new=6, paged=True, page_size=8,
-              prefill_chunk=4, frontend_serve=True, stream=True,
-              verbose=False)
+    kw = dict(n=2, problems=2, max_new=6, page_size=8, prefill_chunk=4,
+              frontend_serve=True, stream=True, verbose=False)
     out = serve_mod.serve_eval(small.name, "kappa", full_width=True, **kw)
     assert out["model"] == {
         "layers": 3, "d_model": 96, "heads": 6, "kv_heads": 2,
@@ -337,3 +334,30 @@ def test_full_width_serves_config_as_published(monkeypatch):
     assert out["status_counts"] == {"OK": 2}
     toy = serve_mod.serve_eval(small.name, "kappa", **kw)["model"]
     assert (toy["layers"], toy["dtype"]) == (2, "float32")
+
+
+def test_scheduler_serve_eval_matches_sequential(monkeypatch):
+    """``serve_eval(..., scheduler=True)`` serves through
+    ``PagedScheduler``, answers exactly as the sequential engine loop
+    does, and leaves no row, page or pin behind."""
+    from repro.launch import serve as serve_mod
+    built = []
+
+    def tracked(*args, **kwargs):
+        built.append(PagedScheduler(*args, **kwargs))
+        return built[-1]
+
+    monkeypatch.setattr(serve_mod, "PagedScheduler", tracked)
+    kw = dict(n=2, problems=3, max_new=8, d_model=64, page_size=8,
+              prefill_chunk=4, prefix_cache=True, verbose=False)
+    seq = serve_mod.serve_eval("deepseek-r1-distill-qwen-1.5b", "kappa",
+                               **kw)
+    assert not built                     # the engine loop, one at a time
+    out = serve_mod.serve_eval("deepseek-r1-distill-qwen-1.5b", "kappa",
+                               scheduler=True, **kw)
+    assert len(built) == 1
+    for key in ("accuracy", "generated_tokens", "final_branch_tokens",
+                "total_tokens", "compute_tokens", "peak_memory_mb"):
+        assert out[key] == seq[key], key
+    assert out["status_counts"] == {"OK": 3}
+    _assert_no_leaks(built[0])

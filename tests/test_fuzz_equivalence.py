@@ -4,11 +4,10 @@ One generated workload — mixed kappa/bon/stbon/greedy strategies,
 random prompt lengths (including page-aligned prompts and prompts
 shorter than one chunk), a random shared preamble so request mixes
 overlap on token prefixes, random per-request ``max_new``, random
-submit order — is served six ways and must stay token-for-token
+submit order — is served five ways and must stay token-for-token
 identical:
 
   * the sequential engine (the reference),
-  * the contiguous scheduler with chunked admission,
   * the paged scheduler with chunked admission (generous pages),
   * the paged scheduler under forced page pressure (preemption live),
   * the paged scheduler with the radix prefix cache on (PR 6): later
@@ -32,7 +31,7 @@ from repro.configs.base import KappaConfig
 from repro.data import tokenizer as tok
 from repro.models import init_params
 from repro.serving import engine
-from repro.serving.scheduler import ContinuousBatchingScheduler, PagedScheduler
+from repro.serving.scheduler import PagedScheduler
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -130,9 +129,6 @@ def _run_case(setup, case):
     tight = max(_worst_pages(m, p, mn, kcfg.num_branches)
                 for m, p, mn in reqs) + 2
     modes = {
-        "contiguous": ContinuousBatchingScheduler(
-            params, cfg, kcfg, rows=8, max_seq=MAX_SEQ, method="kappa",
-            eos_id=tok.EOS, bos_id=tok.BOS, prefill_chunk=chunk),
         "paged": PagedScheduler(
             params, cfg, kcfg, rows=8, max_seq=MAX_SEQ,
             page_size=PAGE_SIZE, num_pages=8 * MAX_SEQ // PAGE_SIZE,
@@ -164,14 +160,13 @@ def _run_case(setup, case):
             assert s.steps == c.steps, ctx
         assert sorted(sched.free) == list(range(8)), name
         assert not sched.prefilling and not sched.active, name
-        if getattr(sched, "pcache", None) is not None:
+        if sched.pcache is not None:
             _allocator_invariants(sched.alloc)   # with live pins
             sched.pcache.drop()                  # tree drop frees pins
-        if hasattr(sched, "alloc"):
-            assert sched.alloc.free_count == sched.num_pages, \
-                f"{name}: leaked pages"
-            assert int(sched.alloc.pinned.sum()) == 0, name
-            _allocator_invariants(sched.alloc)
+        assert sched.alloc.free_count == sched.num_pages, \
+            f"{name}: leaked pages"
+        assert int(sched.alloc.pinned.sum()) == 0, name
+        _allocator_invariants(sched.alloc)
 
 
 # ------------------------------------------------------------- tier-1
@@ -186,7 +181,7 @@ def test_fuzz_equivalence_small(setup):
 
 
 def test_fuzz_equivalence_int8_small(setup):
-    """The tier-1 case replayed with a quantized KV cache: all six
+    """The tier-1 case replayed with a quantized KV cache: all four
     serving modes must stay token-for-token equal to the (also int8)
     sequential reference — COW, preemption replay and the prefix cache
     move quantized pages plus their scale leaves, never re-rounding."""
@@ -218,7 +213,7 @@ TERMINAL = {"OK", "CANCELLED", "TIMEOUT", "FAILED", "SHED"}
 def _run_chaos_case(setup, case):
     """The lifecycle-hardening twin of :func:`_run_case`: the same
     workload served under seeded fault injection plus random cancels
-    and tick budgets, on both schedulers (prefix cache on and off).
+    and tick budgets, with the prefix cache on and off.
     Every request must reach a terminal status, OK survivors must stay
     token-for-token equal to the sequential reference (the fault-replay
     determinism guarantee), and nothing may leak."""
@@ -239,15 +234,13 @@ def _run_chaos_case(setup, case):
                       eos_id=tok.EOS, bos_id=tok.BOS, max_seq=MAX_SEQ))
 
     # a fresh FaultPlan per mode: its memo/fired state is mutable, and
-    # the two backends' tick counts differ
+    # the modes' tick counts differ
     def plan():
         return FaultPlan(seed=case["fault_seed"], max_faults=6)
 
     common = dict(rows=8, max_seq=MAX_SEQ, method="kappa", eos_id=tok.EOS,
                   bos_id=tok.BOS, prefill_chunk=chunk, max_retries=8)
     modes = {
-        "contiguous": lambda: ContinuousBatchingScheduler(
-            params, cfg, kcfg, faults=plan(), **common),
         "paged": lambda: PagedScheduler(
             params, cfg, kcfg, page_size=PAGE_SIZE,
             num_pages=8 * MAX_SEQ // PAGE_SIZE, faults=plan(), **common),
@@ -287,14 +280,13 @@ def _run_chaos_case(setup, case):
                 assert s.logical_tokens == c.logical_tokens, ctx
         # zero-leak: every row, page and pin returned
         assert sorted(sched.free) == list(range(8)), name
-        if getattr(sched, "pcache", None) is not None:
+        if sched.pcache is not None:
             _allocator_invariants(sched.alloc)
             sched.pcache.drop()
-        if hasattr(sched, "alloc"):
-            assert sched.alloc.free_count == sched.num_pages, \
-                f"{name}: leaked pages under chaos"
-            assert int(sched.alloc.pinned.sum()) == 0, name
-            _allocator_invariants(sched.alloc)
+        assert sched.alloc.free_count == sched.num_pages, \
+            f"{name}: leaked pages under chaos"
+        assert int(sched.alloc.pinned.sum()) == 0, name
+        _allocator_invariants(sched.alloc)
 
 
 def _chaos_case_from_seed(seed: int):
@@ -312,7 +304,7 @@ def _chaos_case_from_seed(seed: int):
 @pytest.mark.faults
 def test_chaos_lifecycle_small(setup):
     """Tier-1 chaos case: faults + one mid-run cancel + one tick budget
-    over mixed methods, all three serving modes."""
+    over mixed methods, both serving modes (prefix cache off and on)."""
     case = {"seed": 21, "fault_seed": 5,
             "reqs": [("kappa", 8, 10), ("greedy", 5, 6), ("bon", 9, 6)],
             "order": [2, 0, 1], "chunk": 4, "pre_len": 4,

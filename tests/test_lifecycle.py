@@ -14,13 +14,20 @@ from repro.core import signals
 from repro.data import tokenizer as tok
 from repro.models import init_params
 from repro.serving.faults import FaultPlan, InjectedStepFault, parse_fault_spec
-from repro.serving.scheduler import (ContinuousBatchingScheduler,
-                                     PagedScheduler, Unservable)
+from repro.serving.scheduler import PagedScheduler, Unservable
 
 MAX_SEQ = 32
 PAGE_SIZE = 4
 ROWS = 8
 TERMINAL = {"OK", "CANCELLED", "TIMEOUT", "FAILED", "SHED"}
+# pool configurations, by parameter id: one-shot admission; chunked
+# admission; chunked admission with the radix prefix cache, so that
+# teardown on cancel, timeout and fault publishes prompt pages
+MODES = {
+    "paged-oneshot": {},
+    "paged-chunked": {"prefill_chunk": 4},
+    "paged+prefix": {"prefill_chunk": 4, "prefix_cache": True},
+}
 
 
 @pytest.fixture(scope="module")
@@ -38,32 +45,29 @@ def _prompt(i, plen=7):
     return np.concatenate([[tok.BOS], body, [tok.QM]])
 
 
-def _mk(setup, paged, **kw):
+def _mk(setup, mode, **kw):
     cfg, params, kcfg = setup
     base = dict(rows=ROWS, max_seq=MAX_SEQ, method="kappa",
-                eos_id=tok.EOS, bos_id=tok.BOS)
+                eos_id=tok.EOS, bos_id=tok.BOS, page_size=PAGE_SIZE,
+                num_pages=ROWS * MAX_SEQ // PAGE_SIZE, **MODES[mode])
     base.update(kw)
-    if paged:
-        return PagedScheduler(params, cfg, kcfg, page_size=PAGE_SIZE,
-                              num_pages=ROWS * MAX_SEQ // PAGE_SIZE, **base)
-    return ContinuousBatchingScheduler(params, cfg, kcfg, **base)
+    return PagedScheduler(params, cfg, kcfg, **base)
 
 
 def _assert_no_leaks(sched):
     assert sorted(sched.free) == list(range(sched.rows))
     assert not sched.active and not sched.prefilling and not sched.queue
-    if getattr(sched, "pcache", None) is not None:
+    if sched.pcache is not None:
         sched.pcache.drop()
-    if hasattr(sched, "alloc"):
-        assert sched.alloc.free_count == sched.num_pages, "leaked pages"
-        assert int(sched.alloc.pinned.sum()) == 0, "leaked pins"
+    assert sched.alloc.free_count == sched.num_pages, "leaked pages"
+    assert int(sched.alloc.pinned.sum()) == 0, "leaked pins"
 
 
 # ------------------------------------------------------------- cancel
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_cancel_queued(setup, paged):
-    sched = _mk(setup, paged)
+@pytest.mark.parametrize("mode", ["paged+prefix", "paged-oneshot"])
+def test_cancel_queued(setup, mode):
+    sched = _mk(setup, mode)
     r0 = sched.submit(_prompt(0), jax.random.PRNGKey(0))
     r1 = sched.submit(_prompt(1), jax.random.PRNGKey(1))
     res1 = sched.cancel(r1)          # never admitted: no partial tokens
@@ -79,9 +83,9 @@ def test_cancel_queued(setup, paged):
     _assert_no_leaks(sched)
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_cancel_active_returns_partial_tokens(setup, paged):
-    sched = _mk(setup, paged)
+@pytest.mark.parametrize("mode", ["paged+prefix", "paged-oneshot"])
+def test_cancel_active_returns_partial_tokens(setup, mode):
+    sched = _mk(setup, mode)
     rid = sched.submit(_prompt(0), jax.random.PRNGKey(0), method="greedy",
                        max_new=12)
     for _ in range(5):
@@ -96,9 +100,9 @@ def test_cancel_active_returns_partial_tokens(setup, paged):
     _assert_no_leaks(sched)
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_cancel_mid_prefill(setup, paged):
-    sched = _mk(setup, paged, prefill_chunk=2)
+@pytest.mark.parametrize("mode", ["paged+prefix", "paged-chunked"])
+def test_cancel_mid_prefill(setup, mode):
+    sched = _mk(setup, mode, prefill_chunk=2)
     rid = sched.submit(_prompt(0, plen=7), jax.random.PRNGKey(0))
     sched.tick()                        # admits; 7-token prompt > one chunk
     assert rid in sched.prefilling
@@ -108,14 +112,11 @@ def test_cancel_mid_prefill(setup, paged):
     _assert_no_leaks(sched)
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_cancellation_storm_zero_leak(setup, paged):
+@pytest.mark.parametrize("mode", ["paged-chunked", "paged+prefix"])
+def test_cancellation_storm_zero_leak(setup, mode):
     """Cancel everything — queued, PREFILLING, active — mid-flight; the
     pool must come back empty with every page/pin/slot returned."""
-    kw = dict(prefill_chunk=3)
-    if paged:
-        kw["prefix_cache"] = True
-    sched = _mk(setup, paged, **kw)
+    sched = _mk(setup, mode, prefill_chunk=3)
     rids = [sched.submit(_prompt(i), jax.random.PRNGKey(i))
             for i in range(6)]
     for _ in range(3):
@@ -139,7 +140,7 @@ class _WideFanOut:
 
 
 def test_unservable_is_typed_and_early(setup):
-    sched = _mk(setup, paged=False)
+    sched = _mk(setup, "paged-oneshot")
     assert issubclass(Unservable, ValueError)   # old callers keep working
     with pytest.raises(Unservable, match="max_seq"):
         sched.submit(_prompt(0, plen=MAX_SEQ), jax.random.PRNGKey(0))
@@ -161,9 +162,9 @@ def test_unservable_paged_page_budget(setup):
 
 # ----------------------------------------------------------- deadlines
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_tick_budget_truncates_active(setup, paged):
-    sched = _mk(setup, paged)
+@pytest.mark.parametrize("mode", ["paged+prefix", "paged-oneshot"])
+def test_tick_budget_truncates_active(setup, mode):
+    sched = _mk(setup, mode)
     rid = sched.submit(_prompt(0), jax.random.PRNGKey(0), method="greedy",
                        max_new=12, max_wall_ticks=4)
     out = sched.run()
@@ -174,11 +175,11 @@ def test_tick_budget_truncates_active(setup, paged):
     _assert_no_leaks(sched)
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_tick_budget_expires_queued(setup, paged):
+@pytest.mark.parametrize("mode", ["paged+prefix", "paged-oneshot"])
+def test_tick_budget_expires_queued(setup, mode):
     # 4-row pool: the kappa request (fan-out 4) saturates it, the queued
     # greedy request's one-tick budget expires before it can admit
-    sched = _mk(setup, paged, rows=4)
+    sched = _mk(setup, mode, rows=4)
     r0 = sched.submit(_prompt(0), jax.random.PRNGKey(0))
     r1 = sched.submit(_prompt(1), jax.random.PRNGKey(1), method="greedy",
                       max_wall_ticks=1)
@@ -192,7 +193,7 @@ def test_wall_clock_deadline_truncates_active(setup, fake_clock):
     """Deadline crossing is observed through the injectable clock — no
     real sleeping: decode a few ticks, jump time past the deadline, and
     the next tick's watchdog truncates with the partial tokens kept."""
-    sched = _mk(setup, paged=False, clock=fake_clock)
+    sched = _mk(setup, "paged+prefix", clock=fake_clock)
     rid = sched.submit(_prompt(0), jax.random.PRNGKey(0), method="greedy",
                        max_new=12, deadline_s=5.0)
     for _ in range(3):
@@ -208,12 +209,12 @@ def test_wall_clock_deadline_truncates_active(setup, fake_clock):
     _assert_no_leaks(sched)
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_wall_clock_deadline_expires_queued(setup, paged, fake_clock):
+@pytest.mark.parametrize("mode", ["paged+prefix", "paged-oneshot"])
+def test_wall_clock_deadline_expires_queued(setup, mode, fake_clock):
     # 4-row pool: the kappa request saturates it; the queued greedy
     # request's wall deadline expires (via the fake clock) before a row
     # frees up, so the watchdog sheds it from the queue with no tokens
-    sched = _mk(setup, paged, rows=4, clock=fake_clock)
+    sched = _mk(setup, mode, rows=4, clock=fake_clock)
     r0 = sched.submit(_prompt(0), jax.random.PRNGKey(0))
     r1 = sched.submit(_prompt(1), jax.random.PRNGKey(1), method="greedy",
                       deadline_s=2.0)
@@ -230,9 +231,9 @@ def test_wall_clock_deadline_expires_queued(setup, paged, fake_clock):
 
 # ---------------------------------------------------------------- shed
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_bounded_queue_sheds(setup, paged):
-    sched = _mk(setup, paged, max_queue=2)
+@pytest.mark.parametrize("mode", ["paged+prefix", "paged-oneshot"])
+def test_bounded_queue_sheds(setup, mode):
+    sched = _mk(setup, mode, max_queue=2)
     rids = [sched.submit(_prompt(i), jax.random.PRNGKey(i), method="greedy")
             for i in range(3)]
     assert rids[2] in sched.results     # shed at submit time, terminal
@@ -248,12 +249,12 @@ def test_bounded_queue_sheds(setup, paged):
 # --------------------------------------------------- retry / quarantine
 
 @pytest.mark.faults
-@pytest.mark.parametrize("paged", [False, True])
-def test_step_fault_quarantine_after_max_retries(setup, paged):
+@pytest.mark.parametrize("mode", ["paged+prefix", "paged-oneshot"])
+def test_step_fault_quarantine_after_max_retries(setup, mode):
     """A permanently-faulting device step burns each request's retry
     budget and quarantines it as FAILED — the pool never wedges."""
     plan = FaultPlan(seed=0, p_step=1.0, p_alloc=0.0, p_nan=0.0)
-    sched = _mk(setup, paged, faults=plan, max_retries=1, retry_backoff=1)
+    sched = _mk(setup, mode, faults=plan, max_retries=1, retry_backoff=1)
     rids = [sched.submit(_prompt(i), jax.random.PRNGKey(i), method="greedy")
             for i in range(2)]
     out = sched.run()
@@ -268,17 +269,17 @@ def test_step_fault_quarantine_after_max_retries(setup, paged):
 
 
 @pytest.mark.faults
-@pytest.mark.parametrize("paged", [False, True])
-def test_nan_fault_replay_token_equal(setup, paged):
+@pytest.mark.parametrize("mode", ["paged+prefix", "paged-oneshot"])
+def test_nan_fault_replay_token_equal(setup, mode):
     """NaN-poisoned rows are torn down and replayed from the original
     submission RNG: the survivors' tokens match a fault-free run."""
-    clean = _mk(setup, paged)
+    clean = _mk(setup, mode)
     rids_c = [clean.submit(_prompt(i), jax.random.PRNGKey(i))
               for i in range(3)]
     ref = clean.run()
     plan = FaultPlan(seed=11, p_step=0.0, p_alloc=0.0, p_nan=0.4,
                      nan_rows=2, max_faults=4)
-    sched = _mk(setup, paged, faults=plan, max_retries=8)
+    sched = _mk(setup, mode, faults=plan, max_retries=8)
     rids = [sched.submit(_prompt(i), jax.random.PRNGKey(i))
             for i in range(3)]
     out = sched.run()

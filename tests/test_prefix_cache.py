@@ -185,6 +185,7 @@ def test_repeated_prompt_hits_and_stays_equal(setup):
     assert sched.counters["prefix_hits"] == 1
     assert sched.counters["prefix_tokens_saved"] \
         == ((plen - 1) // PAGE_SIZE) * PAGE_SIZE
+    assert sched.throughput()["prefix_hit_rate"] == 0.5   # miss, then hit
     _teardown_ok(sched)
 
 

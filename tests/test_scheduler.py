@@ -1,6 +1,8 @@
 """Continuous-batching scheduler: token-for-token equivalence with the
 sequential engine, per-row-position decode correctness, row-pool
-lifecycle (admission, prune-backfill, release)."""
+lifecycle (admission, prune-backfill, release). The pool has pages for
+every row's whole ``max_seq``, so no request waits on pages here
+(tests/test_paged.py puts the pool under page pressure)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,7 +14,9 @@ from repro.data import tokenizer as tok
 from repro.models import decode_step, init_params
 from repro.serving import cache as cache_lib
 from repro.serving import engine
-from repro.serving.scheduler import ContinuousBatchingScheduler
+from repro.serving.scheduler import PagedScheduler
+
+PAGE_SIZE = 4
 
 
 @pytest.fixture(scope="module")
@@ -40,11 +44,16 @@ def _sequential(setup, method, **kw):
             for i, p in enumerate(prompts)]
 
 
+def _scheduler(setup, rows, max_seq=None, **sched_kw):
+    cfg, params, kcfg, _, seq_len = setup
+    return PagedScheduler(params, cfg, kcfg, rows=rows,
+                          max_seq=max_seq or seq_len, page_size=PAGE_SIZE,
+                          eos_id=tok.EOS, bos_id=tok.BOS, **sched_kw)
+
+
 def _scheduled(setup, method, rows, **sched_kw):
-    cfg, params, kcfg, prompts, max_seq = setup
-    sched = ContinuousBatchingScheduler(
-        params, cfg, kcfg, rows=rows, max_seq=max_seq, method=method,
-        eos_id=tok.EOS, bos_id=tok.BOS, **sched_kw)
+    prompts = setup[3]
+    sched = _scheduler(setup, rows, method=method, **sched_kw)
     rids = [sched.submit(p, jax.random.PRNGKey(i))
             for i, p in enumerate(prompts)]
     res = sched.run()
@@ -144,9 +153,7 @@ def test_mixed_strategy_pool_matches_sequential(setup):
         fn = getattr(engine, f"generate_{m}")
         seq.append(fn(params, cfg, kc, p, jax.random.PRNGKey(i),
                       eos_id=tok.EOS, bos_id=tok.BOS, max_seq=max_seq))
-    sched = ContinuousBatchingScheduler(
-        params, cfg, kcfg, rows=8, max_seq=max_seq, method="kappa",
-        eos_id=tok.EOS, bos_id=tok.BOS)
+    sched = _scheduler(setup, rows=8, method="kappa")
     rids = [sched.submit(p, jax.random.PRNGKey(i), max_new=mn, method=m)
             for i, (p, (m, mn)) in enumerate(zip(prompts, specs))]
     res = sched.run()
@@ -175,14 +182,10 @@ def test_scheduler_pool_lifecycle(setup):
 
 
 def test_scheduler_rejects_oversized(setup):
-    cfg, params, kcfg, prompts, max_seq = setup
+    prompts = setup[3]
     with pytest.raises(ValueError):
-        ContinuousBatchingScheduler(params, cfg, kcfg, rows=2,
-                                    max_seq=max_seq, method="kappa",
-                                    eos_id=tok.EOS)  # fan-out 4 > 2 rows
-    sched = ContinuousBatchingScheduler(params, cfg, kcfg, rows=4,
-                                        max_seq=8, method="kappa",
-                                        eos_id=tok.EOS)
+        _scheduler(setup, rows=2, method="kappa")  # fan-out 4 > 2 rows
+    sched = _scheduler(setup, rows=4, max_seq=8, method="kappa")
     with pytest.raises(ValueError):
         sched.submit(prompts[0], jax.random.PRNGKey(0))  # prompt+max_new > 8
 

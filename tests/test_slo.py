@@ -12,8 +12,7 @@ from repro.configs import get_config
 from repro.configs.base import KappaConfig
 from repro.data import tokenizer as tok
 from repro.models import init_params
-from repro.serving.scheduler import (ContinuousBatchingScheduler,
-                                     PagedScheduler)
+from repro.serving.scheduler import PagedScheduler
 from repro.serving.slo import SLOConfig, SLOController
 
 MAX_SEQ = 32
@@ -35,21 +34,19 @@ def _prompt(i, plen=7):
     return np.concatenate([[tok.BOS], body, [tok.QM]])
 
 
-def _mk(setup, paged, rows=8, **kw):
+def _mk(setup, rows=8, **kw):
     cfg, params, kcfg = setup
     base = dict(rows=rows, max_seq=MAX_SEQ, method="kappa",
                 eos_id=tok.EOS, bos_id=tok.BOS)
     base.update(kw)
-    if paged:
-        return PagedScheduler(params, cfg, kcfg, page_size=PAGE_SIZE,
-                              num_pages=rows * MAX_SEQ // PAGE_SIZE, **base)
-    return ContinuousBatchingScheduler(params, cfg, kcfg, **base)
+    return PagedScheduler(params, cfg, kcfg, page_size=PAGE_SIZE,
+                          num_pages=rows * MAX_SEQ // PAGE_SIZE, **base)
 
 
 # --------------------------------------------------- windowed snapshot
 
 def test_snapshot_windows_reset(setup, fake_clock):
-    sched = _mk(setup, paged=False, clock=fake_clock)
+    sched = _mk(setup, clock=fake_clock)
     rid = sched.submit(_prompt(0), jax.random.PRNGKey(0), method="greedy",
                        max_new=12)
     sched.tick()                       # admit + first decode at t=0
@@ -81,7 +78,7 @@ def test_snapshot_windows_reset(setup, fake_clock):
 
 
 def test_snapshot_counts_shed(setup):
-    sched = _mk(setup, paged=False, max_queue=1)
+    sched = _mk(setup, max_queue=1)
     sched.submit(_prompt(0), jax.random.PRNGKey(0))
     sched.submit(_prompt(1), jax.random.PRNGKey(1))   # shed at the door
     snap = sched.snapshot()
@@ -178,7 +175,7 @@ def test_prefill_budget_paces_admission(setup):
     """``prefill_budget`` spreads a burst of arrivals across ticks: one
     admission per tick with budget < prompt length, instead of all
     three riding the first tick's dispatch — and nothing is lost."""
-    sched = _mk(setup, paged=True, prefill_chunk=4, method="greedy")
+    sched = _mk(setup, prefill_chunk=4, method="greedy")
     sched.prefill_budget = 1
     rids = [sched.submit(_prompt(i), jax.random.PRNGKey(i),
                          method="greedy", max_new=4) for i in range(3)]
@@ -201,7 +198,7 @@ def test_slo_loop_degrades_then_recovers(setup, fake_clock):
     shrink queue until a submit sheds); freezing the clock makes every
     window healthy and the ladder walks back to level 0, after which
     the queued work drains normally."""
-    sched = _mk(setup, paged=True, rows=2, prefill_chunk=2, max_queue=8,
+    sched = _mk(setup, rows=2, prefill_chunk=2, max_queue=8,
                 method="greedy", clock=fake_clock)
     ctl = SLOController(sched, SLOConfig(target_itl_p99_s=0.1,
                                          window_ticks=4,
@@ -238,5 +235,7 @@ def test_slo_loop_degrades_then_recovers(setup, fake_clock):
     out = sched.run()                         # queued work drains
     assert all(out[r].status == "OK" for r in rids)
     assert sorted(sched.free) == list(range(sched.rows))
+    assert sched.alloc.free_count == sched.num_pages, "leaked pages"
+    assert int(sched.alloc.pinned.sum()) == 0, "leaked pins"
     assert any(h["violated"] for h in ctl.history)
     assert any(h["healthy"] for h in ctl.history)

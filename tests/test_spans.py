@@ -121,11 +121,6 @@ def test_paged_tick_opens_each_phase_span_in_order(setup, notes):
     assert "time_model_s" not in tp and "time_controller_s" not in tp
 
 
-def test_breakdown_keys_are_phases():
-    from benchmarks.throughput import BREAKDOWN_KEYS
-    assert set(BREAKDOWN_KEYS) <= set(spans.PHASES)
-
-
 def test_span_adds_seconds_and_rejects_unknown_phases(notes):
     tt = spans.PhaseTimes()
     with tt.span("host"):
